@@ -78,7 +78,7 @@ from benchmarks.bench_sharded import (  # noqa: E402
 )
 from repro.analysis.corelint import load_baseline, run_corelint  # noqa: E402
 from repro.analysis.protocol_check import CheckConfig, check  # noqa: E402
-from repro.util import atomic_write_text  # noqa: E402
+from repro.util import atomic_write_text, enable_compile_cache  # noqa: E402
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
 CORELINT_BASELINE = REPO_ROOT / "corelint_baseline.json"
@@ -182,6 +182,7 @@ def _update_baseline(base: dict, gates: List[Gate]) -> None:
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    enable_compile_cache()
     quick = "--quick" in argv
     update_baseline = "--update-baseline" in argv
     throughput = bench_proxy_throughput(n_rows=24_576 if quick else 49_152)

@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m benchmarks.run [--full]
 
 Prints ``name,us_per_call,derived`` CSV per benchmark.  --full uses the
-paper-scale query counts (slower); the default profile keeps the whole
-suite under ~15 minutes on this container.
+paper-scale query counts (slower).  Every suite runs even after another
+raised; the run then lists the failures and exits 1.
 """
 from __future__ import annotations
 
@@ -24,6 +24,7 @@ from benchmarks import (  # noqa: E402
     bench_time_reduction,
     roofline,
 )
+from repro.util import enable_compile_cache  # noqa: E402
 
 SUITES = [
     ("correlation_impact (Fig 2, Fig 9)", bench_correlation_impact.run),
@@ -32,16 +33,19 @@ SUITES = [
     ("components (Fig 12, Table 5)", bench_components.run),
     ("scalability (Fig 13)", bench_scalability.run),
     ("accuracy_sweep (Fig 14, Table 6)", bench_accuracy.run),
-    ("roofline (assignment g)", roofline.run),
+    ("roofline (autotune sweep)",
+     lambda quick: roofline.print_sweep(*roofline.cascade_sweep())),
 ]
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale query counts")
     ap.add_argument("--only", default=None, help="substring filter on suite name")
     args = ap.parse_args()
+    enable_compile_cache()
     t_all = time.time()
+    failed = []
     print("name,us_per_call,derived")
     for name, fn in SUITES:
         if args.only and args.only not in name:
@@ -50,11 +54,15 @@ def main() -> None:
         t0 = time.time()
         try:
             fn(quick=not args.full)
-        except Exception as e:  # noqa: BLE001 - a failing suite must not kill the run
+        except Exception as e:  # noqa: BLE001 - the other suites still run
             print(f"bench_error_{name},0,{type(e).__name__}: {e}")
+            failed.append(f"{name}: {type(e).__name__}: {e}")
         print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
     print(f"# total {time.time()-t_all:.1f}s")
+    for line in failed:
+        print(f"# FAILED {line}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

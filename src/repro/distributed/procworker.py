@@ -245,8 +245,17 @@ class ProcessHost:
                  tile: int, policy, seed: int, use_kernel: bool = True,
                  slo_ms: Optional[float] = None,
                  init_timeout_s: float = 600.0):
+        import jax
+
         import repro
 
+        if jax.default_backend() == "tpu":
+            # this process already holds the chip: a child that needs it
+            # fails or hangs, so refuse before starting one
+            raise RuntimeError(
+                "transport='process' starts one JAX process per host, but "
+                "a TPU belongs to one process at a time; use "
+                "transport='inline' or 'thread' on a TPU")
         # repro is a namespace package (__file__ is None): resolve the
         # src dir from its search path instead
         src_dir = Path(list(repro.__path__)[0]).resolve().parent

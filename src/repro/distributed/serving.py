@@ -41,12 +41,14 @@ A real deployment would replace the transport with RPC; the protocol core
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.query import PhysicalPlan
@@ -130,17 +132,37 @@ class ShardedServeStats:
         return sum(r.consensus_ms for r in self.swap_log)
 
 
+def _on_host_device(method):
+    """Run a ``ShardHost`` method under ``jax.default_device(host.device)``
+    — thread-local, so it holds on whichever thread drives the host."""
+
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        with jax.default_device(self.device):
+            return method(self, *args, **kwargs)
+
+    return wrapper
+
+
 class ShardHost:
     """One simulated serving host: a private ``CascadeServer`` whose drift
-    triggers are exported as votes, plus the two-phase staging slot."""
+    triggers are exported as votes, plus the two-phase staging slot.
+
+    Host k is placed on ``jax.devices()[k % n_devices]``: its scorer
+    operands, record tiles and UDF batches all live there, so K hosts on
+    a K-chip machine are K replicas, one per chip.  With one device every
+    host shares it."""
 
     def __init__(self, host_id: int, plan: PhysicalPlan, *, tile: int,
                  policy: AdaptivePolicy, seed: int, use_kernel: bool = True,
                  slo_ms: Optional[float] = None):
         self.host_id = host_id
-        self.engine = CascadeServer(
-            plan, tile=tile, use_kernel=use_kernel, adaptive=True,
-            policy=policy, seed=seed)
+        devices = jax.devices()
+        self.device = devices[host_id % len(devices)]
+        with jax.default_device(self.device):
+            self.engine = CascadeServer(
+                plan, tile=tile, use_kernel=use_kernel, adaptive=True,
+                policy=policy, seed=seed)
         self.query = plan.query
         self.epoch = 0
         self._voted_epoch = -1
@@ -164,8 +186,9 @@ class ShardHost:
             from repro.serving.frontend import ServingFrontEnd, SLOPolicy
 
             self.slo_ms = float(slo_ms)
-            self.frontend = ServingFrontEnd(
-                self.engine, policy=SLOPolicy(degrade=False))
+            with jax.default_device(self.device):
+                self.frontend = ServingFrontEnd(
+                    self.engine, policy=SLOPolicy(degrade=False))
             # version tracking must stamp at ACTUAL engine submission —
             # the front end's batching loop can hold a chunk's tail rows
             # across an epoch install, and those legitimately run (and
@@ -179,6 +202,7 @@ class ShardHost:
                 self.submit_version[int(i)] = v
 
     # ------------------------------------------------------------- serving
+    @_on_host_device
     def submit_chunk(self, indices: np.ndarray, rows: np.ndarray) -> None:
         if self.track_versions and self.frontend is None:
             v = self.engine.plan_version
@@ -194,6 +218,7 @@ class ShardHost:
             self.engine.pump()
         self.submitted += len(rows)
 
+    @_on_host_device
     def drain(self) -> ServeStats:
         if self.frontend is not None:
             while self.frontend.step():
@@ -208,6 +233,7 @@ class ShardHost:
         return st
 
     # -------------------------------------------------------------- voting
+    @_on_host_device
     def poll_vote(self) -> Optional[DriftVote]:
         """Consume a pending local drift trigger into a quorum vote.
         At most one vote per served epoch; repeat triggers within the
@@ -240,6 +266,7 @@ class ShardHost:
         return self.engine.kappa_export()
 
     # --------------------------------------------------------- two-phase
+    @_on_host_device
     def prepare(self, msg: SwapPrepare,
                 timeout: Optional[float] = None) -> SwapAck:
         """Phase 1: deserialize + stage the artifact; serve nothing new.
@@ -262,6 +289,7 @@ class ShardHost:
             return SwapAck(host=self.host_id, epoch=msg.epoch, ok=False,
                            error=str(e), attempt=msg.attempt)
 
+    @_on_host_device
     def commit(self, msg: SwapCommit) -> None:
         """Phase 2: every peer acked — install the staged plan.  In-flight
         queue entries finish under their scoring version."""
@@ -287,6 +315,7 @@ class ShardHost:
         self._staged = None
         self._voted_epoch = -1
 
+    @_on_host_device
     def resync(self, frame: bytes) -> int:
         """Catch-up install for a fenced host rejoining the fleet: a
         COREWIRE v1.1 re-sync frame carries the committed artifact of the

@@ -26,7 +26,9 @@ full-budget block pads 8-16x the rows actually scored.
 Winning configs are cached keyed by (F, HP-bucket, P-bucket, dtype,
 backend, hint-bucket, max_tile); set ``CORE_AUTOTUNE_CACHE=/path.json``
 to persist the table across processes so repeat serving runs skip the
-sweep entirely.
+sweep entirely.  On a TPU the backend is the chip's ``device_kind`` and
+its envelope comes from ``DEVICE_PEAKS``; a TPU missing from that table
+is an error, never the nominal default.
 """
 from __future__ import annotations
 
@@ -36,12 +38,11 @@ from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
-# Nominal single-core accelerator envelope (TPUv4-ish).  Only RATIOS of
-# modeled times ever gate anything, so the absolute calibration is free
-# to be nominal; the byte counts feeding them are exact.  A backend can
-# override these with MEASURED constants via ``calibrate_backend`` /
-# ``set_backend_constants`` — the default path (no registration) uses
-# these module constants unchanged.
+# Nominal envelope for backends without published peaks (the CPU and the
+# "model" sweep backend).  Only RATIOS of modeled times ever gate
+# anything, so the absolute calibration is free to be nominal; the byte
+# counts feeding them are exact.  A backend can override these with
+# MEASURED constants via ``calibrate_backend`` / ``set_backend_constants``.
 HBM_BYTES_PER_S = 1.2e12
 PEAK_FLOPS = 7.0e13
 LAUNCH_OVERHEAD_S = 5.0e-6
@@ -52,7 +53,8 @@ WEIGHT_RESIDENT_BYTES = 4 << 20  # weights this small stay pinned in VMEM
 
 class BackendConstants(NamedTuple):
     """Roofline envelope for one backend.  ``source`` records where the
-    numbers came from: "default" (the baked nominal constants) or
+    numbers came from: "default" (the baked nominal constants),
+    "published" (a chip's datasheet peaks, ``DEVICE_PEAKS``) or
     "measured" (``calibrate_backend`` fitted them from wall-clock)."""
 
     hbm_bytes_per_s: float = HBM_BYTES_PER_S
@@ -65,12 +67,41 @@ class BackendConstants(NamedTuple):
 _DEFAULT_CONSTANTS = BackendConstants()
 _BACKEND_CONSTANTS: dict = {}  # backend name -> BackendConstants
 
+# Published per-chip peaks keyed by ``jax.Device.device_kind`` (Google
+# Cloud documentation, "TPU v5e": 819 GB/s HBM, 197 TFLOP/s bf16).  The
+# overhead terms stay nominal: nothing published prices them.
+DEVICE_PEAKS = {
+    "TPU v5 lite": BackendConstants(hbm_bytes_per_s=819e9,
+                                    peak_flops=197e12, source="published"),
+}
+
+
+def resolve_backend() -> str:
+    """The autotune backend key of the running JAX backend: the platform
+    name, except on a TPU, where it is the chip's ``device_kind`` — which
+    must have an entry in ``DEVICE_PEAKS``."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return backend
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peaks for TPU device_kind {kind!r}: add it to "
+            f"kernels/autotune.DEVICE_PEAKS")
+    return kind
+
 
 def backend_constants(backend: Optional[str] = None) -> BackendConstants:
     """Constants for ``backend`` — the calibrated set if one was
-    registered, the nominal defaults otherwise (so the default path is
-    numerically identical to the pre-calibration tuner)."""
-    return _BACKEND_CONSTANTS.get(str(backend), _DEFAULT_CONSTANTS)
+    registered, else the chip's published peaks, else the nominal
+    defaults (so the CPU path is numerically identical to the
+    pre-calibration tuner)."""
+    key = str(backend)
+    if key in _BACKEND_CONSTANTS:
+        return _BACKEND_CONSTANTS[key]
+    return DEVICE_PEAKS.get(key, _DEFAULT_CONSTANTS)
 
 
 def set_backend_constants(backend: str, constants: BackendConstants) -> None:
@@ -262,9 +293,9 @@ def _load_disk_cache() -> None:
     if not path:
         return
     for key, cfg in _read_disk_table(path).items():
-        # disk entries were swept under the nominal envelope; a backend
-        # running calibrated constants must re-sweep, not inherit them
-        if backend_constants(key[4]).source != "default":
+        # disk entries were swept under nominal or published envelopes; a
+        # backend running calibrated constants must re-sweep, not inherit
+        if backend_constants(key[4]).source == "measured":
             continue
         _CACHE.setdefault(key, cfg)
 
@@ -290,7 +321,7 @@ def _save_disk_cache() -> None:
     # this machine's silicon, and the shared table is read by peers whose
     # calibration (or lack of one) differs
     merged.update({k: v for k, v in _CACHE.items()
-                   if backend_constants(k[4]).source == "default"})
+                   if backend_constants(k[4]).source != "measured"})
     table = {
         json.dumps(list(k)): {
             "block_m": v.block_m, "dtype": v.dtype,
@@ -324,9 +355,7 @@ def choose_block_m(n_features: int, hp: int, n_proxies: int,
     bound; equal bytes at every feasible block, so fewer grid steps win).
     """
     if backend is None:
-        import jax
-
-        backend = jax.default_backend()
+        backend = resolve_backend()
     if not _DISK_LOADED:
         _load_disk_cache()
     hint = max_tile if n_rows_hint is None else int(n_rows_hint)
@@ -356,7 +385,7 @@ def choose_block_m(n_features: int, hp: int, n_proxies: int,
     # calibrated winners are this process's measurement — persisting them
     # would poison peers running under the nominal (or their own
     # measured) envelope, since the disk key does not carry constants
-    if backend_constants(backend).source == "default":
+    if backend_constants(backend).source != "measured":
         _save_disk_cache()
     return cfg
 
@@ -420,9 +449,7 @@ def calibrate_backend(scorer, *, backend: Optional[str] = None,
     constants and pick byte-identical blocks to the pre-calibration
     tuner."""
     if backend is None:
-        import jax
-
-        backend = jax.default_backend()
+        backend = resolve_backend()
     f = int(scorer.n_features)
     hp = int(scorer.w1.shape[1])
     p = int(scorer.n_proxies)

@@ -36,8 +36,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, sk, scale, causal):
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(j * block_k, block_k), :]
+        v = v_ref[pl.ds(j * block_k, block_k), :]
         s = jnp.dot(q, k.astype(jnp.float32).T, preferred_element_type=jnp.float32)
         if causal:
             qpos = q_start + jnp.arange(block_q)

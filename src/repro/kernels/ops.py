@@ -1,8 +1,10 @@
 """Jit'd wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled (interpret=False); in this CPU container
-they run in interpret mode, which executes the kernel body in Python for
-correctness validation — the BlockSpec tiling is identical either way.
+On a TPU backend the kernels run compiled (interpret=False); on the CPU
+they run in Pallas interpret mode, which executes the kernel body for
+correctness validation only.  Interpret mode does not check Mosaic's
+lowering or tiling rules: ``tests/test_tpu_compile.py`` compiles the
+kernels for a described TPU v5e to cover that.
 """
 from __future__ import annotations
 
@@ -12,16 +14,8 @@ import numpy as np
 
 from repro.kernels import autotune
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.proxy_score import cascade_score
+from repro.kernels.proxy_score import cascade_score, interpret_default
 from repro.kernels.ssd_scan import ssd_chunk
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def interpret_default() -> bool:
-    return not _on_tpu()
 
 
 # ----------------------------------------------------------- proxy scoring
@@ -109,6 +103,11 @@ class CascadeScorer:
     Input batches are bucket-padded to a small geometric ladder of static
     shapes so ``jax.jit`` traces a handful of programs total instead of one
     per survivor count; batches larger than the top bucket are chunked.
+
+    The packed operands and every scored tile live on ``self.device``:
+    the device that was current (``current_device``) when the scorer was
+    built, so a serving host inside ``jax.default_device(d)`` scores on
+    ``d``.
     """
 
     def __init__(self, param_list, thresholds, *, block_m: int = None,
@@ -128,14 +127,15 @@ class CascadeScorer:
                 packed = quantize_cascade(packed, dtype)
         self.packed = packed
         self.dtype = packed.dtype
+        self.device = current_device()
         w1, b1, w2, b2 = cascade_kernel_operands(self.packed)
-        self.w1 = jnp.asarray(w1)  # (F, H*P) stacked hidden weights/codes
-        self.b1 = jnp.asarray(b1)
-        self.w2 = jnp.asarray(w2)  # (H*P, P) block-diagonal readout
-        self.b2 = jnp.asarray(b2)
+        self.w1 = self._put(w1)  # (F, H*P) stacked hidden weights/codes
+        self.b1 = self._put(b1)
+        self.w2 = self._put(w2)  # (H*P, P) block-diagonal readout
+        self.b2 = self._put(b2)
         self.out_scale = (None if self.packed.out_scale is None
-                          else jnp.asarray(self.packed.out_scale))
-        self.thr = jnp.asarray(np.asarray(thresholds, np.float32))
+                          else self._put(self.packed.out_scale))
+        self.thr = self._put(np.asarray(thresholds, np.float32))
         self.families = self.packed.families
         self.n_proxies = len(param_list)
         self.n_features = int(self.w1.shape[0])
@@ -250,6 +250,9 @@ class CascadeScorer:
         scorer.stage_cols = list(range(len(params)))
         return scorer, col_maps
 
+    def _put(self, a):
+        return jax.device_put(np.asarray(a), self.device)
+
     def _bucket(self, n: int) -> int:
         for size in self.buckets:
             if n <= size:
@@ -269,7 +272,7 @@ class CascadeScorer:
                     need_compaction: bool = True, compact_cols=None):
         n = x_tile.shape[0]
         scores, mask, packed, counts = cascade_score(
-            jnp.asarray(self._pad_tile(x_tile)), self.w1, self.b1,
+            self._put(self._pad_tile(x_tile)), self.w1, self.b1,
             self.w2, self.b2, self.thr, n, out_scale=self.out_scale,
             block_m=self.block_m, interpret=self.interpret,
             with_scores=need_scores, with_compaction=need_compaction,
@@ -360,7 +363,7 @@ class CascadeScorer:
             tile = x[start:stop]
             m = tile.shape[0]
             scores, mask, _pk, _cnt = cascade_score(
-                jnp.asarray(self._pad_tile(tile)), self.w1, self.b1,
+                self._put(self._pad_tile(tile)), self.w1, self.b1,
                 self.w2, self.b2, self.thr, m, out_scale=self.out_scale,
                 block_m=self.block_m, interpret=self.interpret,
                 with_scores=True, with_compaction=False,
@@ -403,19 +406,29 @@ def params_fingerprint(params) -> str:
     return h.hexdigest()
 
 
+def current_device():
+    """The device new arrays land on: the ``jax.default_device`` in
+    effect (thread-local), else the backend's first device."""
+    dev = jax.config.jax_default_device
+    return jax.devices()[0] if dev is None else dev
+
+
 def _plan_scorer_key(plan, max_tile: int):
     # no family component: the packed fingerprint already determines the
     # compiled program bit-for-bit, so e.g. a deserialized wire copy
     # ("packed1" family) of a locally-built linear plan hits the same entry.
     # The quant dtype IS a key component: the same fp32 params packed at
     # int8 vs fp32 are different compiled programs (different codes and
-    # masks), so a stale-dtype scorer must never be served.
+    # masks), so a stale-dtype scorer must never be served.  So is the
+    # device: hosts placed on different chips must not share operands.
+    dev = current_device()
     return tuple(
         (s.pred_idx,
          params_fingerprint(s.proxy.params) if s.proxy is not None else None,
          float(s.threshold))
         for s in plan.stages
-    ) + (int(max_tile), str(plan.meta.get("quant_dtype", "float32")))
+    ) + (int(max_tile), str(plan.meta.get("quant_dtype", "float32")),
+         (dev.platform, dev.id))
 
 
 def cascade_scorer_for_plan(plan, *, max_tile: int = 8192):

@@ -31,18 +31,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _make_cascade_kernel(n_proxies, with_scores, with_compaction):
-    """Fused whole-cascade tile kernel: hidden GEMM + relu, then the
-    block-diagonal readout GEMM scores every stage column; optionally a
-    block-local prefix sum packs survivor positions so the wrapper can
-    assemble dense per-stage survivor index lists without a host
-    round-trip.
+def interpret_default() -> bool:
+    """Pallas interpret mode everywhere but a TPU backend: the compiled
+    kernel exists only for the chip, the interpreter validates it on the
+    CPU."""
+    return jax.default_backend() != "tpu"
 
-    The prefix sum runs over the first ``n_proxies`` (real) columns only —
-    the lane-pad columns are all-False and would triple the scan cost.
-    ``with_scores`` / ``with_compaction`` drop output writes the caller
-    won't read (each is a full (block_m, P) HBM round-trip): the serving
-    engine gates on masks alone, the executor needs masks + compaction.
+
+def _make_cascade_kernel(n_proxies, with_scores):
+    """Fused whole-cascade tile kernel: hidden GEMM + relu, then the
+    block-diagonal readout GEMM scores every stage column.
+
+    Every operand is 2-D and the valid/mask tiles are int32: Mosaic
+    lowers neither 1-D lane indexing nor ``bool`` vector refs.
+    ``with_scores`` drops the score write the caller won't read (a full
+    (block_m, Pp) HBM round-trip): the serving engine gates on masks
+    alone.
     """
 
     def kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, thr_ref, scale_ref,
@@ -53,7 +57,7 @@ def _make_cascade_kernel(n_proxies, with_scores, with_compaction):
         # traffic is 1 byte/weight, the arithmetic stays f32
         hid = jnp.dot(x.astype(jnp.float32), w1_ref[...].astype(jnp.float32),
                       preferred_element_type=jnp.float32)
-        hid = jnp.maximum(hid + b1_ref[...][None, :], 0.0)
+        hid = jnp.maximum(hid + b1_ref[...], 0.0)
         # readout over the REAL stage columns only — the lane-pad columns
         # of w2 are all-zero and would multiply the second GEMM's cost by
         # ~128/P for nothing (the MXU pads the n-dim internally either way)
@@ -62,21 +66,13 @@ def _make_cascade_kernel(n_proxies, with_scores, with_compaction):
         # the single dequantizing multiply: per-stage readout scales (all
         # ones for fp32 cascades — ``x * 1.0`` is an IEEE identity, so the
         # fp32 path stays bit-exact through this op)
-        s = s * scale_ref[...][None, :n_proxies] + b2_ref[...][None, :n_proxies]
-        m = (s >= thr_ref[...][None, :n_proxies]) & valid_ref[...]
+        s = s * scale_ref[...][:, :n_proxies] + b2_ref[...][:, :n_proxies]
+        keep = (s >= thr_ref[...][:, :n_proxies]) & (valid_ref[...] != 0)
+        m = jnp.where(keep, 1, 0).astype(jnp.int32)
         pad = w2_ref.shape[1] - n_proxies
-        refs = list(out_refs)
         if with_scores:
-            refs.pop(0)[...] = jnp.pad(s, ((0, 0), (0, pad)))
-        refs.pop(0)[...] = jnp.pad(m, ((0, 0), (0, pad)))
-        if with_compaction:
-            mi = m.astype(jnp.int32)
-            inclusive = jnp.cumsum(mi, axis=0)
-            if pad:
-                inclusive = jnp.pad(inclusive, ((0, 0), (0, pad)))
-                mi = jnp.pad(mi, ((0, 0), (0, pad)))
-            refs.pop(0)[...] = inclusive - mi  # local packed slot per row
-            refs.pop(0)[...] = inclusive[-1:, :]  # block survivor totals
+            out_refs[0][...] = jnp.pad(s, ((0, 0), (0, pad)))
+        out_refs[-1][...] = jnp.pad(m, ((0, 0), (0, pad)))
 
     return kernel
 
@@ -92,7 +88,7 @@ def _pm_pack_linear_operands(w, b):
     return w1, b1, w2, jnp.zeros((P,), jnp.float32)
 
 
-def proxy_score(x, w, b, thresholds, *, block_m: int = 256, interpret: bool = True):
+def proxy_score(x, w, b, thresholds, *, block_m: int = 256, interpret=None):
     """x: (N, F); w: (F, P); b, thresholds: (P,).
 
     Linear-stack convenience: returns (scores (N, P) f32, mask (N, P)
@@ -113,7 +109,7 @@ def proxy_score(x, w, b, thresholds, *, block_m: int = 256, interpret: bool = Tr
     "block_m", "interpret", "with_scores", "with_compaction", "compact_cols"))
 def cascade_score(x, w1, b1, w2, b2, thresholds, n_valid, *,
                   out_scale=None,
-                  block_m: int = 256, interpret: bool = True,
+                  block_m: int = 256, interpret=None,
                   with_scores: bool = True, with_compaction: bool = True,
                   compact_cols=None):
     """One fused two-pass GEMM over a record tile for a whole cascade.
@@ -129,6 +125,7 @@ def cascade_score(x, w1, b1, w2, b2, thresholds, n_valid, *,
     None means ones — the fp32 path, bit-identical to the pre-quantization
     kernel (``x * 1.0`` preserves every bit).  ``w1``/``w2`` may be int8
     code matrices; they widen to f32 in-register after the VMEM load.
+    ``interpret=None`` follows ``interpret_default()``.
 
     Returns:
       scores (N, P) f32          raw proxy scores (None if not with_scores)
@@ -143,17 +140,19 @@ def cascade_score(x, w1, b1, w2, b2, thresholds, n_valid, *,
       counts (P,)  int32         survivors per stage, ALL columns (None
                                  when not with_compaction)
 
-    Compaction runs on device: the kernel emits block-local exclusive
-    prefix sums + per-block totals; this wrapper turns them into global
-    packed slots with an inter-block scan and a single scatter, so a dense
-    UDF batch index list exists without materialising the boolean mask on
-    the host.  ``with_scores=False`` / ``with_compaction=False`` drop the
-    outputs (and their HBM round-trips) a caller won't read — the serving
-    engine gates on masks alone.  ``compact_cols`` gates the scatter
-    assembly per column: the executor consumes the packed list only for
-    its first full-tile stage, so later columns' O(N) scatters are skipped
-    instead of computed-then-discarded.
+    Compaction runs on device, in XLA after the kernel: an exclusive
+    prefix sum of the assembled columns' masks gives each survivor its
+    packed slot and a single scatter writes the index lists, so a dense
+    UDF batch index list exists without materialising the boolean mask
+    on the host.  ``with_scores=False`` / ``with_compaction=False`` drop
+    the outputs a caller won't read — the serving engine gates on masks
+    alone.  ``compact_cols`` gates the scan and scatter per column: the
+    executor consumes the packed list only for its first full-tile
+    stage, so later columns' O(N) scatters are skipped instead of
+    computed-then-discarded.
     """
+    if interpret is None:
+        interpret = interpret_default()
     N, F = x.shape
     HP = w1.shape[1]
     P = w2.shape[1]
@@ -174,65 +173,51 @@ def cascade_score(x, w1, b1, w2, b2, thresholds, n_valid, *,
         thresholds = jnp.pad(thresholds, (0, pad_p), constant_values=jnp.inf)
         out_scale = jnp.pad(out_scale, (0, pad_p), constant_values=1.0)
     Np, HPp, Pp = x.shape[0], w1.shape[1], w2.shape[1]
-    valid = (jnp.arange(Np, dtype=jnp.int32) < n_valid)[:, None]
+    valid = (jnp.arange(Np, dtype=jnp.int32) < n_valid).astype(jnp.int32)
 
-    nb = Np // block_m
     tile_spec = pl.BlockSpec((block_m, Pp), lambda i: (i, 0))
-    out_specs, out_shape = [], []
+    row_spec = pl.BlockSpec((1, Pp), lambda i: (0, 0))
+    out_specs = [tile_spec]
+    out_shape = [jax.ShapeDtypeStruct((Np, Pp), jnp.int32)]
     if with_scores:
-        out_specs.append(tile_spec)
-        out_shape.append(jax.ShapeDtypeStruct((Np, Pp), jnp.float32))
-    out_specs.append(tile_spec)
-    out_shape.append(jax.ShapeDtypeStruct((Np, Pp), jnp.bool_))
-    if with_compaction:
-        out_specs += [tile_spec, pl.BlockSpec((1, Pp), lambda i: (i, 0))]
-        out_shape += [jax.ShapeDtypeStruct((Np, Pp), jnp.int32),
-                      jax.ShapeDtypeStruct((nb, Pp), jnp.int32)]
+        out_specs.insert(0, tile_spec)
+        out_shape.insert(0, jax.ShapeDtypeStruct((Np, Pp), jnp.float32))
     outs = pl.pallas_call(
-        _make_cascade_kernel(P, with_scores, with_compaction),
-        grid=(nb,),
+        _make_cascade_kernel(P, with_scores),
+        grid=(Np // block_m,),
         in_specs=[
             pl.BlockSpec((block_m, F), lambda i: (i, 0)),
             pl.BlockSpec((F, HPp), lambda i: (0, 0)),
-            pl.BlockSpec((HPp,), lambda i: (0,)),
+            pl.BlockSpec((1, HPp), lambda i: (0, 0)),
             pl.BlockSpec((HPp, Pp), lambda i: (0, 0)),
-            pl.BlockSpec((Pp,), lambda i: (0,)),
-            pl.BlockSpec((Pp,), lambda i: (0,)),
-            pl.BlockSpec((Pp,), lambda i: (0,)),
+            row_spec, row_spec, row_spec,
             pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(x, w1, b1, w2, b2, thresholds, out_scale, valid)
-    outs = list(outs)
-    scores = outs.pop(0) if with_scores else None
-    mask = outs.pop(0)
-    mask_p = mask[:, :P]
+    )(x, w1, b1[None, :], w2, b2[None, :], thresholds[None, :],
+      out_scale[None, :], valid[:, None])
+    scores = outs[0][:N, :P] if with_scores else None
+    mask_p = outs[-1][:, :P] != 0
     if not with_compaction:
-        return (scores[:N, :P] if with_scores else None,
-                mask_p[:N], None, None)
-    pos, cnt = outs
+        return scores, mask_p[:N], None, None
 
-    # inter-block exclusive scan of the per-block survivor counts gives each
-    # block its base slot; scatter rows to (stage, slot), dropping rejects.
-    # Assembly runs only over the REAL P columns — the lane-pad columns are
-    # all-False and would multiply the scatter cost ~128/P for nothing —
-    # and, when ``compact_cols`` names the columns a caller will actually
-    # consume, only over those.
+    # exclusive scan of each assembled column gives every survivor its
+    # packed slot; scatter rows to (stage, slot), dropping rejects.
+    # Assembly runs only over the REAL P columns — the lane-pad columns
+    # are all-False and would multiply the scatter cost ~128/P for
+    # nothing — and, when ``compact_cols`` names the columns a caller
+    # will actually consume, only over those.
     cols_sel = tuple(range(P)) if compact_cols is None else tuple(compact_cols)
-    ci = jnp.asarray(cols_sel, jnp.int32)
+    mask_sel = mask_p[:, jnp.asarray(cols_sel, jnp.int32)]  # (Np, C)
     C = len(cols_sel)
-    cnt_sel = cnt[:, ci]  # (nb, C)
-    block_base = jnp.cumsum(cnt_sel, axis=0) - cnt_sel
-    gpos = pos[:, ci] + jnp.repeat(block_base, block_m, axis=0,
-                                   total_repeat_length=Np)
-    mask_sel = mask_p[:, ci]
+    mi = mask_sel.astype(jnp.int32)
+    gpos = jnp.cumsum(mi, axis=0) - mi
     gpos = jnp.where(mask_sel, gpos, Np)  # sentinel slot -> dropped by scatter
     rows = jnp.broadcast_to(jnp.arange(Np, dtype=jnp.int32)[:, None], (Np, C))
     cols = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None, :], (Np, C))
     packed = jnp.full((C, Np), -1, jnp.int32).at[cols, gpos].set(
         rows, mode="drop")
-    counts = jnp.sum(cnt[:, :P], axis=0)
-    return (scores[:N, :P] if with_scores else None,
-            mask_p[:N], packed[:, :N], counts)
+    counts = jnp.sum(mask_p.astype(jnp.int32), axis=0)
+    return scores, mask_p[:N], packed[:, :N], counts

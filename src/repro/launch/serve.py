@@ -43,6 +43,7 @@ from repro.data.synthetic import (
     make_udfs,
 )
 from repro.serving.engine import CascadeServer
+from repro.util import enable_compile_cache
 
 
 @dataclass
@@ -206,6 +207,7 @@ def config_from_args(args: argparse.Namespace) -> LaunchConfig:
 
 def main():
     args = build_arg_parser().parse_args()
+    enable_compile_cache()
     cfg = config_from_args(args)
     wl, opt, sv = cfg.workload, cfg.optimize, cfg.serve
 

@@ -193,12 +193,12 @@ class CascadeServer:
             stage_in=[0] * n, stage_udf_batches=[0] * n, stage_kept=[0] * n,
             stage_proxy_ms=[0.0] * n, stage_used_kernel=[False] * n,
         )
-        self._scorer = None  # legacy per-stage kernel fallback
+        # per-stage kernel path (``fused=False``, parity runs); with
+        # ``use_kernel=False`` proxies score on the host — the test oracle
+        self._scorer = None
         if use_kernel:
-            try:
-                from repro.kernels.ops import proxy_score_batch
-            except ImportError:  # pragma: no cover - kernel optional
-                proxy_score_batch = None
+            from repro.kernels.ops import proxy_score_batch
+
             self._scorer = proxy_score_batch
         # cross-query UDF evaluation hook (serving/multiquery.py): when a
         # session installs a runner, ``_eval_udf`` routes every stage and
@@ -235,6 +235,11 @@ class CascadeServer:
     def plan_version(self) -> int:
         return self._states[-1].version
 
+    @property
+    def cascade(self):
+        """The current plan version's fused scorer (None without one)."""
+        return self._states[-1].cascade
+
     def _install(self, plan: PhysicalPlan, *, scorer=None,
                  version: Optional[int] = None):
         cascade = None
@@ -248,9 +253,10 @@ class CascadeServer:
             # a from_plan failure is a real bug — let it propagate
             built, hit = cascade_scorer_for_plan(
                 plan, max_tile=max(self.tile, 1024))
-            # score-at-submit only pays off when every gated stage is
-            # covered; otherwise fall back to per-stage kernel calls
-            if built is not None and built.covers_all(plan):
+            if built is not None:
+                if not built.covers_all(plan):
+                    raise ValueError(
+                        "fused scorer does not cover every proxied stage")
                 cascade = built
                 self.stats.scorer_cache_hits += int(hit)
         if version is None:

@@ -15,11 +15,15 @@ DESIGN.md §9 for the rule catalog they anchor):
   file + ``os.replace`` publish, the pattern ``kernels/autotune.py``
   hardened in PR 7 after a concurrent writer tore its disk cache.  Any
   shared-path ``open(path, "w")`` outside this pattern is a lint error.
+
+``enable_compile_cache`` turns on JAX's persistent compilation cache for
+the entry points (never at import).
 """
 from __future__ import annotations
 
 import os
 import time
+from pathlib import Path
 
 
 def advisory_wall_ms() -> float:
@@ -58,3 +62,22 @@ def atomic_write_bytes(path, data: bytes) -> None:
 def atomic_write_text(path, text: str, encoding: str = "utf-8") -> None:
     """``atomic_write_bytes`` for text content."""
     atomic_write_bytes(path, text.encode(encoding))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Called by entry points only.  A ``JAX_COMPILATION_CACHE_DIR`` set in
+    the environment is left to JAX; otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` — the path is part of what a later
+    run has to find again, so it never carries a temporary name, pid or
+    time.  Every program is cached, however quick its compile: a cold
+    process on the chip compiles all of them again otherwise."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

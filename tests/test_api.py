@@ -24,6 +24,53 @@ from repro.launch.serve import (
 )
 
 
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and no other directory is set in
+    code; unset, the cache sits at the fixed ``<checkout>/.jax_cache``."""
+    from pathlib import Path
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.util import enable_compile_cache
+
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before[0]
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = Path(__file__).resolve().parents[1]
+        assert enable_compile_cache() == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            checkout / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          before[1])
+        compilation_cache.reset_cache()
+
+
+def test_benchmark_runner_fails_on_a_failing_suite(monkeypatch, capsys):
+    """Every suite still runs after one raised; the run then names the
+    failure and exits non-zero."""
+    from benchmarks import run
+
+    ran = []
+
+    def broken(quick):
+        raise RuntimeError("boom")
+
+    monkeypatch.setattr(run, "SUITES", [("broken", broken),
+                                        ("fine", lambda quick: ran.append(quick))])
+    monkeypatch.setattr(run, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr("sys.argv", ["run"])
+    assert run.main() == 1
+    assert ran == [True]
+    assert "# FAILED broken: RuntimeError: boom" in capsys.readouterr().out
+
+
 @pytest.fixture(scope="module")
 def workload():
     ds = make_dataset(n=4000, correlation=0.9, seed=17)

@@ -638,6 +638,24 @@ def test_pooled_kappa_disabled_by_default(workload):
 
 
 # ------------------------------------------------- process transport
+def test_process_transport_refuses_a_tpu(monkeypatch):
+    """On a TPU the parent process holds the chip, so a process host
+    refuses at construction instead of starting a child that cannot get
+    it."""
+    import jax
+
+    from repro.distributed import procworker
+
+    def no_child(*_a, **_k):
+        raise AssertionError("a worker process was started")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(procworker.subprocess, "Popen", no_child)
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        procworker.ProcessHost(0, spec={}, artifact=b"", tile=64,
+                               policy=AdaptivePolicy(), seed=0)
+
+
 @pytest.mark.slow
 @pytest.mark.flaky
 def test_process_transport_fleet(workload):

@@ -516,6 +516,28 @@ def test_uncalibrated_backend_is_bit_identical(clean_backends):
         assert a == b
 
 
+def test_tpu_backend_priced_by_device_kind(clean_backends, monkeypatch):
+    """On a TPU the autotune backend is the chip's device_kind, priced by
+    its published peaks; a TPU with no table entry raises instead of
+    borrowing the nominal envelope, and the CPU path does not move."""
+    import jax
+
+    class Chip:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    assert autotune.resolve_backend() == "TPU v5 lite"
+    v5e = autotune.backend_constants("TPU v5 lite")
+    assert (v5e.hbm_bytes_per_s, v5e.peak_flops, v5e.source) == (
+        819e9, 197e12, "published")
+    assert autotune.choose_block_m(64, 4, 2).source == "sweep"
+    Chip.device_kind = "TPU v99"
+    with pytest.raises(ValueError, match="no published peaks"):
+        autotune.choose_block_m(64, 4, 2)
+    assert autotune.backend_constants("cpu") == autotune.BackendConstants()
+
+
 def test_set_backend_constants_reprices_and_invalidates(clean_backends):
     """Registered constants change modeled time for THAT backend only,
     and evict its cached sweep winners (a winner picked under the

@@ -55,6 +55,19 @@ def test_cascade_server_kernel_path(small_plan):
     assert a.emitted == b.emitted
 
 
+def test_cascade_server_refuses_partial_scorer(small_plan, monkeypatch):
+    """A fused scorer that misses a proxied stage is an error at install,
+    never a quiet fall back to per-stage scoring."""
+    from repro.kernels.ops import CascadeScorer
+    from repro.serving.engine import CascadeServer
+
+    _ds, _q, plan = small_plan
+    assert any(s.proxy is not None for s in plan.stages)
+    monkeypatch.setattr(CascadeScorer, "covers_all", lambda self, p: False)
+    with pytest.raises(ValueError, match="does not cover"):
+        CascadeServer(plan, tile=128)
+
+
 # -------------------------------------------------------------- checkpointer
 def test_checkpoint_roundtrip(tmp_path):
     import jax.numpy as jnp

@@ -1,6 +1,7 @@
 """Multi-host sharded serving (DESIGN.md §6): scorer wire format, quorum
 vote + two-phase swap protocol, merged-reservoir estimator equivalence,
 and K=4 end-to-end conservation across a quorum-voted plan swap."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -464,6 +465,62 @@ def test_thread_transport_conservation(workload):
     stats = srv.run_streams([s.x for s in streams], chunk=400)
     assert stats.submitted == stats.emitted + stats.rejected
     assert {h.epoch for h in srv.hosts} == {stats.final_epoch}
+
+
+def test_hosts_placed_on_devices(workload, mixed_plan):
+    """Host k scores on ``jax.devices()[k % n]``; with one device every
+    host shares it, exactly as before placement existed."""
+    import jax
+
+    srv = ShardedCascadeServer(mixed_plan, 3, tile=256, policy=_policy(),
+                               seed=3)
+    devices = jax.devices()
+    for h in srv.hosts:
+        assert h.device == devices[h.host_id % len(devices)]
+        assert h.engine.cascade.w1.devices() == {h.device}
+
+
+PLACEMENT_SUBPROC = textwrap.dedent(
+    """
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path.insert(0, "src")
+    import jax
+    from repro.core import OptimizeOptions, build_plan
+    from repro.data.synthetic import make_dataset, make_query, make_udfs
+    from repro.distributed.serving import ShardedCascadeServer
+
+    ds = make_dataset(n=3000, n_features=64, n_columns=2, seed=5)
+    udfs = make_udfs(ds, hidden=16, depth=1, train_rows=800, seed=5,
+                     declared_cost_ms=10.0)
+    q = make_query(ds, udfs, columns=[0, 1], target_selectivity=0.5,
+                   accuracy_target=0.9, seed=6)
+    plan = build_plan(q, ds.x[:800], OptimizeOptions(mode="core-a",
+                                                     step=0.05))
+    assert len(jax.devices()) == 4
+    srv = ShardedCascadeServer(plan, 4, tile=256, seed=3)
+    stats = srv.run_stream(ds.x[800:2400], chunk=400)
+    assert stats.submitted == stats.emitted + stats.rejected
+    placed = [sorted(d.id for d in h.engine.cascade.w1.devices())
+              for h in srv.hosts]
+    assert placed == [[0], [1], [2], [3]], placed
+    print("PLACED_OK", placed)
+    """
+)
+
+
+def test_hosts_placed_on_distinct_devices_subprocess():
+    """Four virtual devices, four hosts: each host's scorer operands sit
+    on its own device (own process: the device count is fixed at JAX
+    start-up)."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    r = subprocess.run(
+        [sys.executable, "-c", PLACEMENT_SUBPROC], capture_output=True,
+        text=True, cwd=str(Path(__file__).resolve().parents[1]), env=env,
+        timeout=300)
+    assert "PLACED_OK" in r.stdout, (
+        f"stdout:\n{r.stdout[-2000:]}\nstderr:\n{r.stderr[-3000:]}")
 
 
 SUBPROC = textwrap.dedent(
