@@ -157,10 +157,30 @@ _WALL_CLOCK_ATTRS = frozenset(
 )
 
 
+#: ``repro.util.spans`` reads the clock on the same footing as
+#: ``advisory_wall_ms``: a decision-path module may open spans, but the
+#: span totals (``snapshot()``) are wall time and are for reports only.
+_SPANS_MODULE = "repro.util.spans"
+
+
+def _is_spans_read(node: ast.Attribute) -> bool:
+    owner = _name_of(node.value)
+    return node.attr == "snapshot" and owner is not None and (
+        owner == "spans" or owner.endswith(".spans"))
+
+
 def _check_wall_clock(ctx: FileContext) -> List[Tuple[int, str]]:
     out: List[Tuple[int, str]] = []
     for node in ast.walk(ctx.tree):
-        if isinstance(node, ast.Attribute):
+        if isinstance(node, ast.Attribute) and _is_spans_read(node):
+            out.append(
+                (
+                    node.lineno,
+                    f"reading span totals `{_name_of(node)}` in a decision-path "
+                    "module; span time is wall time, for reports only",
+                )
+            )
+        elif isinstance(node, ast.Attribute):
             name = _name_of(node)
             if name and name.startswith("time.") and node.attr in _WALL_CLOCK_ATTRS:
                 out.append(
@@ -180,6 +200,15 @@ def _check_wall_clock(ctx: FileContext) -> List[Tuple[int, str]]:
                         node.lineno,
                         f"importing clock function(s) {bad} from time into a decision-path "
                         "module; use repro.util.advisory_wall_ms()",
+                    )
+                )
+        elif isinstance(node, ast.ImportFrom) and node.module == _SPANS_MODULE:
+            if any(a.name == "snapshot" for a in node.names):
+                out.append(
+                    (
+                        node.lineno,
+                        "importing span totals (`snapshot`) into a decision-path "
+                        "module; span time is wall time, for reports only",
                     )
                 )
     return out
@@ -577,7 +606,7 @@ def _check_deprecated_entry_point(ctx: FileContext) -> List[Tuple[int, str]]:
 RULES: List[Rule] = [
     Rule(
         id="wall-clock-decision",
-        summary="no raw wall-clock reads in decision-path modules",
+        summary="no raw wall-clock or span-total reads in decision-path modules",
         origin="PR 7: wall-clock fused_score_ms nearly fed scheduling; decisions must run "
         "on the cost-model clock (advisory_wall_ms is the one sanctioned read)",
         applies=_in_decision_scope,

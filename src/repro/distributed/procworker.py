@@ -226,8 +226,7 @@ class _RemoteEngineView:
         from repro.serving.engine import ServeStats
 
         self.stats = ServeStats(stage_in=[], stage_udf_batches=[],
-                                stage_kept=[], stage_proxy_ms=[],
-                                stage_used_kernel=[])
+                                stage_kept=[], stage_used_kernel=[])
         self.emitted: list = []
         self.emitted_versions: list = []
         self.plan_version = 0

@@ -16,6 +16,7 @@ from repro.kernels import autotune
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.proxy_score import cascade_score, interpret_default
 from repro.kernels.ssd_scan import ssd_chunk
+from repro.util.spans import span, spanned
 
 
 # ----------------------------------------------------------- proxy scoring
@@ -271,17 +272,19 @@ class CascadeScorer:
     def _score_tile(self, x_tile: np.ndarray, need_scores: bool,
                     need_compaction: bool = True, compact_cols=None):
         n = x_tile.shape[0]
-        scores, mask, packed, counts = cascade_score(
-            self._put(self._pad_tile(x_tile)), self.w1, self.b1,
-            self.w2, self.b2, self.thr, n, out_scale=self.out_scale,
-            block_m=self.block_m, interpret=self.interpret,
-            with_scores=need_scores, with_compaction=need_compaction,
-            compact_cols=compact_cols,
-        )
-        return (np.asarray(scores[:n]) if need_scores else None,
-                np.asarray(mask[:n]),
-                np.asarray(packed) if need_compaction else None,
-                np.asarray(counts) if need_compaction else None)
+        with span("scorer.launch"):
+            scores, mask, packed, counts = cascade_score(
+                self._put(self._pad_tile(x_tile)), self.w1, self.b1,
+                self.w2, self.b2, self.thr, n, out_scale=self.out_scale,
+                block_m=self.block_m, interpret=self.interpret,
+                with_scores=need_scores, with_compaction=need_compaction,
+                compact_cols=compact_cols,
+            )
+        with span("scorer.fetch"):
+            return (np.asarray(scores[:n]) if need_scores else None,
+                    np.asarray(mask[:n]),
+                    np.asarray(packed) if need_compaction else None,
+                    np.asarray(counts) if need_compaction else None)
 
     def score_compact(self, x: np.ndarray, *, need_scores: bool = False,
                       compact_cols=None):
@@ -329,6 +332,7 @@ class CascadeScorer:
                            else np.empty(0, np.int32))
         return scores, masks, packed, counts
 
+    @spanned("scorer.score")
     def score_masks(self, x: np.ndarray) -> np.ndarray:
         """Per-stage keep masks only (N, P): skips the compaction outputs
         and their device round-trips — the serving engine's submit-time
@@ -343,6 +347,7 @@ class CascadeScorer:
             masks[start:stop] = mask
         return masks
 
+    @spanned("scorer.score")
     def score_margins(self, x: np.ndarray):
         """Masks (N, P) plus per-record distance to the NEAREST stage
         threshold (N,) — the importance-audit weight signal (records near
@@ -362,15 +367,17 @@ class CascadeScorer:
             stop = min(start + self.max_tile, n)
             tile = x[start:stop]
             m = tile.shape[0]
-            scores, mask, _pk, _cnt = cascade_score(
-                self._put(self._pad_tile(tile)), self.w1, self.b1,
-                self.w2, self.b2, self.thr, m, out_scale=self.out_scale,
-                block_m=self.block_m, interpret=self.interpret,
-                with_scores=True, with_compaction=False,
-            )
-            masks[start:stop] = np.asarray(mask[:m])
-            margins[start:stop] = np.asarray(
-                jnp.min(jnp.abs(scores[:m] - self.thr[None, :]), axis=1))
+            with span("scorer.launch"):
+                scores, mask, _pk, _cnt = cascade_score(
+                    self._put(self._pad_tile(tile)), self.w1, self.b1,
+                    self.w2, self.b2, self.thr, m, out_scale=self.out_scale,
+                    block_m=self.block_m, interpret=self.interpret,
+                    with_scores=True, with_compaction=False,
+                )
+            with span("scorer.fetch"):
+                masks[start:stop] = np.asarray(mask[:m])
+                margins[start:stop] = np.asarray(
+                    jnp.min(jnp.abs(scores[:m] - self.thr[None, :]), axis=1))
         return masks, margins
 
 

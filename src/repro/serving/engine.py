@@ -53,6 +53,7 @@ from repro.serving.stats import (
     StreamingRate,
 )
 from repro.util import advisory_wall_ms
+from repro.util.spans import span, spanned
 
 
 @dataclass
@@ -60,13 +61,11 @@ class ServeStats:
     stage_in: List[int]
     stage_udf_batches: List[int]
     stage_kept: List[int]
-    stage_proxy_ms: List[float]
     stage_used_kernel: List[bool]
     emitted: int = 0
     rejected: int = 0
     wall_ms: float = 0.0
     model_cost_ms: float = 0.0
-    fused_score_ms: float = 0.0  # submit-time fused whole-cascade scoring
     # ----- adaptive serving -----
     plan_swaps: int = 0
     reopt_ms: float = 0.0  # wall time inside re-optimization
@@ -76,10 +75,6 @@ class ServeStats:
     scorer_cache_hits: int = 0
     plan_cache_writebacks: int = 0  # committed plans recorded cross-query
     drift_events: List[DriftEvent] = field(default_factory=list)
-
-    @property
-    def proxy_total_ms(self) -> float:
-        return self.fused_score_ms + sum(self.stage_proxy_ms)
 
 
 class _AuditMonitor:
@@ -191,7 +186,7 @@ class CascadeServer:
         self.emitted_versions: List[int] = []
         self.stats = ServeStats(
             stage_in=[0] * n, stage_udf_batches=[0] * n, stage_kept=[0] * n,
-            stage_proxy_ms=[0.0] * n, stage_used_kernel=[False] * n,
+            stage_used_kernel=[False] * n,
         )
         # per-stage kernel path (``fused=False``, parity runs); with
         # ``use_kernel=False`` proxies score on the host — the test oracle
@@ -355,9 +350,11 @@ class CascadeServer:
                           version: int) -> None:
         if not self._finalize_hooks or not (emitted or rejected):
             return
-        for fn in self._finalize_hooks:
-            fn(emitted, rejected, version)
+        with span("engine.finalize"):
+            for fn in self._finalize_hooks:
+                fn(emitted, rejected, version)
 
+    @spanned("engine.submit")
     def submit(self, indices: np.ndarray, rows: np.ndarray, *,
                masks: Optional[np.ndarray] = None,
                margins: Optional[np.ndarray] = None):
@@ -381,7 +378,6 @@ class CascadeServer:
             for i, r, m in zip(indices, rows, masks):
                 cur.queues[0].append((int(i), r, m))
         elif cur.cascade is not None and len(rows):
-            t0 = advisory_wall_ms()
             if self.adaptive and self.policy.audit_importance:
                 # the importance-audit weights need score-to-threshold
                 # distances; the margin reduction runs on device in the
@@ -389,7 +385,6 @@ class CascadeServer:
                 masks, margins = cur.cascade.score_margins(rows)
             else:
                 masks = cur.cascade.score_masks(rows)
-            self.stats.fused_score_ms += advisory_wall_ms() - t0
             for i, r, m in zip(indices, rows, masks):
                 cur.queues[0].append((int(i), r, m))
         else:
@@ -399,6 +394,7 @@ class CascadeServer:
             self._observe_chunk(np.asarray(indices), rows, margins)
         self._records_submitted += len(rows)
 
+    @spanned("engine.udf")
     def _eval_udf(self, pred, idxs: np.ndarray, x: np.ndarray):
         """Run ``pred``'s UDF over ``x`` and return (labels, cost_ms).
         The default path runs and charges everything; a session-installed
@@ -467,6 +463,7 @@ class CascadeServer:
                  >= self.policy.cooldown_records)
         )
 
+    @spanned("engine.stage")
     def _run_stage_batch(self, state: _PlanState, si: int, batch: List):
         stage = state.plan.stages[si]
         idxs = np.asarray([b[0] for b in batch])
@@ -476,7 +473,6 @@ class CascadeServer:
         n_enter = len(batch)
         rejected_ids: List[int] = []
         if stage.proxy is not None:
-            t0 = advisory_wall_ms()
             col = state.cascade.stage_cols[si] if state.cascade is not None else None
             if col is not None and mrows[0] is not None:
                 # fused path: the gate was computed once at submit time
@@ -487,7 +483,6 @@ class CascadeServer:
                 self.stats.stage_used_kernel[si] = True
             else:
                 keep = stage.proxy.score(x) >= stage.threshold
-            self.stats.stage_proxy_ms[si] += advisory_wall_ms() - t0
             self.stats.model_cost_ms += len(x) * stage.proxy.cost
             rejected_ids.extend(int(i) for i in idxs[~keep])
             idxs, x = idxs[keep], x[keep]
@@ -546,6 +541,7 @@ class CascadeServer:
                 batch = [q.popleft() for _ in range(take)]
                 self._run_stage_batch(state, si, batch)
 
+    @spanned("engine.pump")
     def pump(self, *, drain: bool = False):
         """Run every stage whose queue holds >= one full tile.  Superseded
         plan versions flush completely first — their in-flight entries
@@ -556,6 +552,7 @@ class CascadeServer:
                         if s is self._states[-1] or not s.empty()]
         self._pump_state(self._states[-1], drain=drain)
 
+    @spanned("engine.pump")
     def pump_one(self, *, drain: bool = False) -> bool:
         """Run AT MOST one stage batch — the multi-query scheduler's
         service quantum: it charges the cost-model delta of exactly one

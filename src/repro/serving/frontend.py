@@ -23,9 +23,10 @@ latency metric.  This module adds the request level (DESIGN.md §7):
     **shed explicitly** — counted, attributed, and never silently lost.
 
 Time base: everything is the engine's deterministic cost-model clock
-(``ServeStats.model_cost_ms``), NOT wall-clock — ``fused_score_ms`` is
-host time and never enters any decision or reported metric here, so runs
-are bit-reproducible and gateable (DESIGN.md §2).
+(``ServeStats.model_cost_ms``), NOT wall-clock — host time (the
+program spans of ``repro.util.spans``) never enters any decision or
+reported metric here, so runs are bit-reproducible and gateable
+(DESIGN.md §2).
 
 Conservation contract (property-tested): every admitted record is
 exactly one of {emitted, rejected-by-the-cascade, explicitly shed};

@@ -158,7 +158,6 @@ class SessionStats:
     restacks: int = 0
     shared_cols: int = 0          # columns in the stacked scorer
     stacked_cols_saved: int = 0   # columns deduped across tenants
-    shared_score_ms: float = 0.0  # wall inside the stacked fused pass
     finalized_per_query: List[int] = field(default_factory=list)
 
 
@@ -269,9 +268,7 @@ class MultiQueryEngine:
         targets = range(len(self.servers)) if qids is None else qids
         full = None
         if self.scorer is not None:
-            t0 = advisory_wall_ms()
             full = self.scorer.score_masks(rows)
-            self.stats.shared_score_ms += advisory_wall_ms() - t0
         for q in targets:
             srv = self.servers[q]
             if full is not None and self._gcols[q]:
@@ -370,7 +367,6 @@ class MultiQueryEngine:
             "restacks": self.stats.restacks,
             "shared_cols": self.stats.shared_cols,
             "stacked_cols_saved": self.stats.stacked_cols_saved,
-            "shared_score_ms": self.stats.shared_score_ms,
             "model_cost_ms": self.model_cost_ms(),
             "dedupe": self.udf_cache.as_dict(),
             "scheduler": self.scheduler.as_dict(),

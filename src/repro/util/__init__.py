@@ -11,6 +11,8 @@ DESIGN.md §9 for the rule catalog they anchor):
   only.  Funneling every read through one explicitly-named helper makes
   the corelint allowlist a single function instead of a module list —
   a raw ``time.perf_counter()`` in a decision module is a lint error.
+  ``repro.util.spans`` (program spans, off unless enabled) reads the
+  clock on the same footing; its totals are for reports only.
 * ``atomic_write_text`` / ``atomic_write_bytes`` — same-directory temp
   file + ``os.replace`` publish, the pattern ``kernels/autotune.py``
   hardened in PR 7 after a concurrent writer tore its disk cache.  Any

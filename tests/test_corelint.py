@@ -82,13 +82,38 @@ def test_clean_twin_is_silent(rule_id):
     assert suppressed == 0
 
 
+#: fixtures beyond the per-rule triples: (relpath, rule id, violating line)
+EXTRA_BAD = [("serving/spans_snapshot_bad.py", "wall-clock-decision", 6)]
+EXTRA_CLEAN = ["serving/spans_snapshot_clean.py"]
+
+
 def test_run_corelint_over_fixture_tree():
     report = run_corelint([FIXTURES], root=FIXTURES.parent.parent)
-    assert report.files_scanned == 30
+    assert report.files_scanned == 3 * len(EXPECTED) + len(EXTRA_BAD) + len(EXTRA_CLEAN)
     assert report.parse_errors == []
     got = {(v.path.split("lint_fixtures/")[1], v.rule) for v in report.violations}
-    assert got == {(rel, rid) for rid, (rel, _l) in EXPECTED.items()}
+    assert got == {(rel, rid) for rid, (rel, _l) in EXPECTED.items()} | {
+        (rel, rid) for rel, rid, _l in EXTRA_BAD}
     assert report.suppressed == len(EXPECTED)
+
+
+@pytest.mark.parametrize("rel,rule_id,line", EXTRA_BAD)
+def test_span_totals_read_in_a_decision_module_is_a_finding(rel, rule_id, line):
+    violations, _ = _lint_fixture(rel)
+    assert [(v.rule, v.line) for v in violations] == [(rule_id, line)]
+    for clean in EXTRA_CLEAN:
+        assert _lint_fixture(clean) == ([], 0)
+
+
+@pytest.mark.parametrize("src", [
+    "from repro.util.spans import snapshot\n",
+    "import repro.util.spans\nx = repro.util.spans.snapshot()\n",
+])
+def test_span_totals_are_read_freely_outside_decision_modules(src):
+    inside, _ = lint_source(src, "src/repro/core/mod.py")
+    assert [v.rule for v in inside] == ["wall-clock-decision"]
+    outside, _ = lint_source(src, "benchmarks/chip/tools/mod.py")
+    assert outside == []
 
 
 # ---------------------------------------------------------------- baseline

@@ -3,6 +3,8 @@ survivor indices must EXACTLY match the reference oracle, across ragged
 tile sizes (N not a multiple of block_m), the P > 128 lane-pad path,
 empty-survivor stages, MLP and mixed-family cascades (hidden-width
 bucket boundaries included)."""
+import contextlib
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -12,6 +14,18 @@ from repro.core.proxy_family import cascade_kernel_operands
 from repro.kernels import ref
 from repro.kernels.ops import CascadeScorer, fold_standardizer
 from repro.training.proxy_models import LinearParams, MLPParams
+from repro.util import spans
+
+
+@contextlib.contextmanager
+def recording():
+    """Program spans on, from empty totals, for the block."""
+    spans.reset()
+    spans.enable()
+    try:
+        yield
+    finally:
+        spans.disable()
 
 
 def _make_params(rng, F, P):
@@ -322,14 +336,15 @@ def test_server_mixed_cascade_all_stages_kernel():
     plan = optimize(q, ds.x[:800], mode="core-a", step=0.05, kind="mixed")
     x = ds.x[1000:4000]
     a = CascadeServer(plan, tile=257, use_kernel=True)
-    sa = a.run_stream(x, chunk=700)
+    with recording():
+        sa = a.run_stream(x, chunk=700)
     b = CascadeServer(plan, tile=257, use_kernel=False)
     sb = b.run_stream(x, chunk=700)
     # boundary ties allowed (MLP fold reassociation), see executor test
     assert len(set(a.emitted) ^ set(b.emitted)) <= 3
     assert sa.emitted + sa.rejected == len(x)
     assert all(sa.stage_used_kernel)
-    assert sa.fused_score_ms > 0.0
+    assert spans.snapshot()["scorer.launch"][0] > 0
 
 
 def test_server_fused_stats_and_parity():
@@ -344,12 +359,13 @@ def test_server_fused_stats_and_parity():
     plan = optimize(q, ds.x[:800], mode="core-a", step=0.05)
     x = ds.x[1000:4000]
     a = CascadeServer(plan, tile=257, use_kernel=True)
-    sa = a.run_stream(x, chunk=700)
+    with recording():
+        sa = a.run_stream(x, chunk=700)
     b = CascadeServer(plan, tile=257, use_kernel=False)
     sb = b.run_stream(x, chunk=700)
     assert a.emitted == b.emitted
     assert sa.emitted + sa.rejected == len(x)
     assert all(sa.stage_used_kernel)
     assert not any(sb.stage_used_kernel)
-    assert sa.fused_score_ms > 0.0
+    assert spans.snapshot()["scorer.launch"][0] > 0
     assert abs(sa.model_cost_ms - sb.model_cost_ms) < 1e-6
