@@ -13,9 +13,9 @@ The paper's executor streams rows; on TPU we keep static shapes (DESIGN.md
 Fused hot path: a ``CascadeScorer`` covers EVERY proxied stage — linear,
 MLP, or mixed, all lowered to the packed ProxyFamily format — and scores
 each incoming chunk ONCE at submit time: one fused two-pass Pallas GEMM
-yields every stage's keep decision, and the per-record mask rows ride
-through the stage queues with the record.  Stage execution then never
-re-packs, re-scores, or re-traces: the gate is a mask lookup.
+yields every stage's keep decision, and the chunk's mask rows ride
+through the columnar stage queues with its records.  Stage execution
+then never re-packs, re-scores, or re-traces: the gate is a mask column.
 
 Adaptive serving (DESIGN.md §4): with ``adaptive=True`` the server keeps
 streaming statistics — per-stage observed keep-rates vs the plan's
@@ -75,6 +75,9 @@ class ServeStats:
     scorer_cache_hits: int = 0
     plan_cache_writebacks: int = 0  # committed plans recorded cross-query
     drift_events: List[DriftEvent] = field(default_factory=list)
+    # per stage: queued segments its batches were cut from (1 per take
+    # when a batch is a zero-copy slice of one segment)
+    stage_take_segments: List[int] = field(default_factory=list)
 
 
 class _AuditMonitor:
@@ -126,11 +129,67 @@ class _AuditMonitor:
         return sum(k for k, _, _ in self._window) / seen if seen else 0.0
 
 
+class _StageQueue:
+    """FIFO of columnar segments ``(ids (n,) int64, rows (n, F) float32,
+    masks (n, P) bool | None)``, with an offset into the head segment and
+    a running length.  ``push`` appends a segment; ``take`` cuts the next
+    records as one slice of the head segment, or one concatenation when
+    they span several.  Every operation is a fixed number of numpy calls
+    per segment touched, never a Python step per record."""
+
+    __slots__ = ("_segs", "_head", "_len")
+
+    def __init__(self):
+        self._segs: deque = deque()
+        self._head = 0
+        self._len = 0
+
+    def __len__(self) -> int:
+        return self._len
+
+    def push(self, ids: np.ndarray, rows: np.ndarray,
+             masks: Optional[np.ndarray]) -> None:
+        if not len(ids):
+            return
+        # one state has one cascade: its queues never mix masked and
+        # unmasked segments
+        assert not self._segs or (masks is None) == (self._segs[-1][2] is None)
+        self._segs.append((ids, rows, masks))
+        self._len += len(ids)
+
+    def take(self, n: int):
+        """Pop the next ``min(n, len)`` records of a non-empty queue:
+        ``(ids, rows, masks, segments)``, ``segments`` the number of
+        queued segments they were cut from."""
+        n = min(n, self._len)
+        parts = []
+        while n > 0:
+            ids, rows, masks = self._segs[0]
+            h = self._head
+            k = min(n, len(ids) - h)
+            parts.append((ids[h:h + k], rows[h:h + k],
+                          None if masks is None else masks[h:h + k]))
+            if h + k == len(ids):
+                self._segs.popleft()
+                self._head = 0
+            else:
+                self._head = h + k
+            self._len -= k
+            n -= k
+        if len(parts) == 1:
+            return (*parts[0], 1)
+        ids, rows, masks = zip(*parts)
+        return (np.concatenate(ids), np.concatenate(rows),
+                None if masks[0] is None else np.concatenate(masks),
+                len(parts))
+
+
 class _PlanState:
     """One installed plan version: its compiled scorer, its stage queues,
-    and (while current) its drift monitors.  Queue entries are
-    (global idx, feature row, mask row | None); the mask row is only ever
-    interpreted through THIS state's ``stage_cols`` — versioned masks."""
+    and (while current) its drift monitors.  Each stage queue is a
+    ``_StageQueue`` of (global ids, feature rows, mask rows | None)
+    segments; a mask row is only ever interpreted through THIS state's
+    ``stage_cols`` — versioned masks."""
 
     def __init__(self, version: int, plan: PhysicalPlan, cascade,
                  policy: Optional[AdaptivePolicy]):
@@ -138,7 +197,7 @@ class _PlanState:
         self.plan = plan
         self.cascade = cascade
         n = len(plan.stages)
-        self.queues: List[deque] = [deque() for _ in range(n)]
+        self.queues: List[_StageQueue] = [_StageQueue() for _ in range(n)]
         self.stage_rate = [StreamingRate() for _ in range(n)]
         self.stage_cusum = (
             [CusumDetector(policy.slack, policy.threshold) for _ in range(n)]
@@ -186,7 +245,7 @@ class CascadeServer:
         self.emitted_versions: List[int] = []
         self.stats = ServeStats(
             stage_in=[0] * n, stage_udf_batches=[0] * n, stage_kept=[0] * n,
-            stage_used_kernel=[False] * n,
+            stage_used_kernel=[False] * n, stage_take_segments=[0] * n,
         )
         # per-stage kernel path (``fused=False``, parity runs); with
         # ``use_kernel=False`` proxies score on the host — the test oracle
@@ -343,7 +402,9 @@ class CascadeServer:
         stage, or rejected by a proxy gate / predicate at any stage).
         Every submitted record is reported to the hooks exactly once —
         the serving front end leans on this for per-request completion
-        latency attribution (DESIGN.md §7)."""
+        latency attribution (DESIGN.md §7).  Both id arguments are
+        ``list``s of Python ``int``s in queue order (gate rejects before
+        predicate rejects); a side with no ids is ``[]``."""
         self._finalize_hooks.append(fn)
 
     def _notify_finalized(self, emitted: List[int], rejected: List[int],
@@ -363,11 +424,15 @@ class CascadeServer:
         stacked launch for every tenant and hands each engine its own
         column slice.  Mask rows are versioned exactly like locally
         scored ones: they ride the current state's queues and are only
-        read through its ``stage_cols``."""
+        read through its ``stage_cols``.
+
+        The chunk is queued as one segment without copying ``rows`` (nor
+        ``masks``): the caller must not write into them until the
+        records have left the pipeline."""
         if len(rows) == 0:
             # short-circuit: the front end's batching loop ticks on every
-            # arrival-poll, so idle ticks would otherwise still walk the
-            # zip-append path and count into ``_records_submitted`` (whose
+            # arrival-poll, so idle ticks would otherwise still push an
+            # empty segment and count into ``_records_submitted`` (whose
             # delta since the last swap feeds the ``_may_trigger``
             # cooldown arithmetic) — an empty submission must be a no-op
             return
@@ -375,9 +440,7 @@ class CascadeServer:
         rows = np.asarray(rows, np.float32)
         if masks is not None:
             masks = np.asarray(masks, bool)
-            for i, r, m in zip(indices, rows, masks):
-                cur.queues[0].append((int(i), r, m))
-        elif cur.cascade is not None and len(rows):
+        elif cur.cascade is not None:
             if self.adaptive and self.policy.audit_importance:
                 # the importance-audit weights need score-to-threshold
                 # distances; the margin reduction runs on device in the
@@ -385,12 +448,8 @@ class CascadeServer:
                 masks, margins = cur.cascade.score_margins(rows)
             else:
                 masks = cur.cascade.score_masks(rows)
-            for i, r, m in zip(indices, rows, masks):
-                cur.queues[0].append((int(i), r, m))
-        else:
-            for i, r in zip(indices, rows):
-                cur.queues[0].append((int(i), r, None))
-        if self.adaptive and len(rows):
+        cur.queues[0].push(np.array(indices, np.int64), rows, masks)
+        if self.adaptive:
             self._observe_chunk(np.asarray(indices), rows, margins)
         self._records_submitted += len(rows)
 
@@ -464,53 +523,52 @@ class CascadeServer:
         )
 
     @spanned("engine.stage")
-    def _run_stage_batch(self, state: _PlanState, si: int, batch: List):
+    def _run_stage_batch(self, state: _PlanState, si: int, ids: np.ndarray,
+                         x: np.ndarray, masks: Optional[np.ndarray]):
         stage = state.plan.stages[si]
-        idxs = np.asarray([b[0] for b in batch])
-        x = np.stack([b[1] for b in batch])
-        mrows = [b[2] for b in batch]
-        self.stats.stage_in[si] += len(batch)
-        n_enter = len(batch)
-        rejected_ids: List[int] = []
+        n_enter = len(ids)
+        self.stats.stage_in[si] += n_enter
+        gate_rejected = ids[:0]
         if stage.proxy is not None:
             col = state.cascade.stage_cols[si] if state.cascade is not None else None
-            if col is not None and mrows[0] is not None:
+            if col is not None and masks is not None:
                 # fused path: the gate was computed once at submit time
-                keep = np.asarray([m[col] for m in mrows], bool)
+                keep = masks[:, col]
                 self.stats.stage_used_kernel[si] = True
             elif self._scorer is not None:
                 keep = self._scorer(stage.proxy.params, x, stage.threshold)
                 self.stats.stage_used_kernel[si] = True
             else:
                 keep = stage.proxy.score(x) >= stage.threshold
-            self.stats.model_cost_ms += len(x) * stage.proxy.cost
-            rejected_ids.extend(int(i) for i in idxs[~keep])
-            idxs, x = idxs[keep], x[keep]
-            mrows = [m for m, k in zip(mrows, keep) if k]
-        if len(idxs) == 0:
+            self.stats.model_cost_ms += n_enter * stage.proxy.cost
+            gate_rejected = ids[~keep]
+            ids, x = ids[keep], x[keep]
+            if masks is not None:
+                masks = masks[keep]
+        if len(ids) == 0:
             self._note_stage_outcome(state, si, 0, n_enter)
-            self._notify_finalized([], rejected_ids, state.version)
+            self._notify_finalized([], gate_rejected.tolist(), state.version)
             return
         pred = state.plan.query.predicates[stage.pred_idx]
-        labels, udf_cost = self._eval_udf(pred, idxs, x)
+        labels, udf_cost = self._eval_udf(pred, ids, x)
         self.stats.model_cost_ms += udf_cost
         self.stats.stage_udf_batches[si] += 1
         passed = pred.evaluate(labels)
-        self.stats.stage_kept[si] += int(passed.sum())
-        rejected_ids.extend(int(i) for i in idxs[~passed])
-        survivors = [
-            (int(i), r, m) for i, r, m, p in zip(idxs, x, mrows, passed) if p
-        ]
-        self._note_stage_outcome(state, si, len(survivors), n_enter)
-        emitted_ids: List[int] = []
+        kept = int(np.count_nonzero(passed))
+        self.stats.stage_kept[si] += kept
+        rejected = np.concatenate((gate_rejected, ids[~passed])).tolist()
+        ids = ids[passed]
+        self._note_stage_outcome(state, si, kept, n_enter)
+        emitted: List[int] = []
         if si + 1 < len(state.plan.stages):
-            state.queues[si + 1].extend(survivors)
+            state.queues[si + 1].push(
+                ids, x[passed], None if masks is None else masks[passed])
         else:
-            emitted_ids = [i for i, _, _ in survivors]
-            self.emitted.extend(emitted_ids)
-            self.emitted_versions.extend([state.version] * len(survivors))
-            self.stats.emitted += len(survivors)
-        self._notify_finalized(emitted_ids, rejected_ids, state.version)
+            emitted = ids.tolist()
+            self.emitted.extend(emitted)
+            self.emitted_versions.extend([state.version] * kept)
+            self.stats.emitted += kept
+        self._notify_finalized(emitted, rejected, state.version)
 
     def _note_stage_outcome(self, state: _PlanState, si: int, kept: int,
                             seen: int):
@@ -537,9 +595,14 @@ class CascadeServer:
         for si in order:
             q = state.queues[si]
             while len(q) >= self.tile or (drain and q):
-                take = min(self.tile, len(q))
-                batch = [q.popleft() for _ in range(take)]
-                self._run_stage_batch(state, si, batch)
+                self._run_next_batch(state, si)
+
+    def _run_next_batch(self, state: _PlanState, si: int):
+        """Cut the next ``tile`` records (or what is left) off stage
+        ``si``'s queue and run them as one stage batch."""
+        ids, x, masks, segments = state.queues[si].take(self.tile)
+        self.stats.stage_take_segments[si] += segments
+        self._run_stage_batch(state, si, ids, x, masks)
 
     @spanned("engine.pump")
     def pump(self, *, drain: bool = False):
@@ -570,9 +633,7 @@ class CascadeServer:
             for si in order:
                 q = state.queues[si]
                 if len(q) >= self.tile or (flush and q):
-                    take = min(self.tile, len(q))
-                    batch = [q.popleft() for _ in range(take)]
-                    self._run_stage_batch(state, si, batch)
+                    self._run_next_batch(state, si)
                     return True
         return False
 
