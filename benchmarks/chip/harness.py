@@ -3,6 +3,12 @@
 A cell is a configuration (``configs/<config>.json``) under a traffic mix
 (``traffic/<mix>.json``), both named in ``BENCHMARK.json``; per-layer
 metrics are read by ``metrics/<metric>.py``.  Nothing here names a cell.
+Everything the configuration's UDFs decide (the record process and the
+width and type of its rows, the served UDFs, their warm-up, their labels,
+what the proxies see, their FLOPs and any check of their own) comes from
+its UDF family, ``families/<udf_family>.py`` (``mlp`` where the key is
+absent), and the proxy reference from the module its ``reference`` key
+names (``reference.py`` where absent).
 
 What the seed decides: the served records (fresh draws of the
 configuration's record process, one per record id, never served
@@ -28,7 +34,6 @@ BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parents[1]
 KERNEL_OP = r"^%cascade_score(\.\d+)? = "
 BLOCK_ROWS = 1 << 17        # records drawn per call of the stream's sampler
-REF_BLOCK_ROWS = 1 << 14    # rows per UDF call of the reference
 
 
 class NoChip(RuntimeError):
@@ -144,47 +149,43 @@ def _import_local(name: str):
 
 
 wl = _import_local("workload")
-reference = _import_local("reference")
 counts = _import_local("counts")
 trace_mod = _import_local("trace")
 
 
 class Model:
-    """The configuration's record process, UDFs, queries and optimization
-    sample, built from ``model_seed``."""
+    """The configuration's UDF family (record process and UDFs), proxy
+    reference, queries and optimization sample, built from
+    ``model_seed``.
+
+    A family module's ``build(cfg, load)`` returns an object with:
+    ``block_sampler(block_rows)``, the stream's ``draw(words, b) -> rows``
+    (it decides the rows' width and type); ``udfs``, the served callables
+    ``rows -> labels``; ``x_model``, the rows the queries' values and the
+    optimization sample come from; ``warm()``, every UDF batch shape the
+    engine can send; ``labels_for_query(x)`` and ``orig_labels(x)``, every
+    UDF's labels; ``proxy_inputs(rows)``, what the scorer sees;
+    ``n_proxy_features``; ``udf_flops(rows)``; and
+    ``extra_checks(x_window, limits) -> {name: (value, op, limit)}``."""
 
     def __init__(self, cfg: dict, probe: Probe):
         from repro.core.query import MLUDF, Predicate, Query
 
         self.cfg = cfg
         seed = int(cfg["model_seed"])
-        proc, x_model, truth = wl.make_process(
-            n_features=cfg["n_features"], n_latent=cfg["n_latent"],
-            n_columns=cfg["n_columns"], n_classes=cfg["n_classes"],
-            correlation=cfg["correlation"], label_noise=cfg["label_noise"],
-            feature_noise=cfg["feature_noise"], n_rows=cfg["model_rows"],
-            seed=seed)
-        self.process = proc
-        idx = np.random.RandomState(seed).choice(
-            len(x_model), min(cfg["udf_train_rows"], len(x_model)),
-            replace=False)
-        params = wl.train_udfs(
-            x_model[idx], truth[idx], hidden=cfg["udf_hidden"],
-            depth=cfg["udf_depth"], n_classes=cfg["n_classes"],
-            steps=cfg["udf_train_steps"], seed=seed)
-        self.fwd = wl.UdfForward(params, cfg["udf_pad_rows"])
-        self.udf_dims = wl.udf_layer_dims(
-            cfg["n_features"], cfg["udf_hidden"], cfg["udf_depth"],
-            cfg["n_classes"])
-        self.block = int(cfg["tile"])
+        self.family = _import_local(
+            f"families/{cfg.get('udf_family', 'mlp')}").build(
+                cfg, _import_local)
+        self.reference = _import_local(
+            cfg.get("reference", "reference.py").removesuffix(".py"))
         self.probe = probe
-        model_labels = [wl.labels_in_blocks(self.fwd, j, x_model, self.block)
-                        for j in range(cfg["n_columns"])]
+        x_model = self.family.x_model
+        model_labels = self.family.labels_for_query(x_model)
         self.udfs = [MLUDF(name=f"{cfg['name']}.udf{j}",
                            fn=self._served_udf(j),
                            cost=float(cfg["udf_declared_cost_ms"]),
                            n_classes=int(cfg["n_classes"]))
-                     for j in range(cfg["n_columns"])]
+                     for j in range(len(self.family.udfs))]
         self.queries = []
         self.query_values = []
         for qi, cols in enumerate(cfg["queries"]):
@@ -199,20 +200,15 @@ class Model:
         self.x_sample = x_model[:cfg["sample_rows"]]
 
     def _served_udf(self, j: int):
-        fwd = self.fwd
+        udf = self.family.udfs[j]
 
         def fn(x):
             probe = self.probe
             probe.add("udf_rows", len(x))
             with probe.span("bench.udf"):
-                return fwd(j, x)
+                return udf(x)
 
         return fn
-
-    def orig_labels(self, x: np.ndarray) -> List[np.ndarray]:
-        """Every UDF on every row (the ORIG plan's work), in blocks."""
-        return [wl.labels_in_blocks(self.fwd, j, x, REF_BLOCK_ROWS)
-                for j in range(self.cfg["n_columns"])]
 
 
 # ------------------------------------------------------------ sessions
@@ -353,10 +349,12 @@ class RecordSource:
     """The served stream: one fresh draw of the record process per
     globally unique record id, so no record is served twice.  Record id
     ``i`` is row ``i % block_rows`` of block ``i // block_rows``, and a
-    block is drawn on the device from the run seed and its index alone.
+    block is drawn on the device from the run seed and its index alone,
+    by ``process.block_sampler``, which decides the rows' width and type.
     Set-up draws the blocks of the first ``setup_rows`` ids; a window that
     outruns them draws the next block when it gets there (the same
-    compiled sampler, so nothing compiles)."""
+    compiled sampler, so nothing compiles), and keeps the blocks drawn
+    before as they are."""
 
     def __init__(self, process, seed: int, setup_rows: int,
                  block_rows: int = BLOCK_ROWS):
@@ -364,28 +362,38 @@ class RecordSource:
         self._draw = process.block_sampler(self.block)
         self._words = np.random.SeedSequence(
             [seed % (1 << 64), 1]).generate_state(2, np.uint32)
-        self.x = np.empty((0, process.w_feat.shape[1]), np.float32)
+        self.blocks: List[np.ndarray] = []
         self.next_id = 0
         self._cover(int(setup_rows))
 
+    @property
+    def x(self) -> np.ndarray:
+        """Every row drawn so far, in id order (a copy of the blocks)."""
+        return np.concatenate(self.blocks)
+
     def _cover(self, n: int) -> None:
-        """Draw every block up to the one holding id ``n - 1``."""
-        have = len(self.x) // self.block
-        want = -(-n // self.block)
-        if want <= have:
-            return
-        x = np.empty((want * self.block, self.x.shape[1]), np.float32)
-        x[:len(self.x)] = self.x
-        for b in range(have, want):
-            x[b * self.block:(b + 1) * self.block] = np.asarray(
-                self._draw(self._words, np.int32(b)))
-        self.x = x
+        """Draw every block up to the one holding id ``n - 1``, each kept
+        row-major: a TPU hands a block back column-major, and gathering
+        rows from that is about 500 times slower."""
+        for b in range(len(self.blocks), -(-n // self.block)):
+            self.blocks.append(np.ascontiguousarray(
+                self._draw(self._words, np.int32(b))))
 
     def rows(self, ids: np.ndarray) -> np.ndarray:
         ids = np.asarray(ids, np.int64)
-        if len(ids):
-            self._cover(int(ids.max()) + 1)
-        return np.take(self.x, ids, axis=0)
+        if not len(ids):
+            return np.take(self.blocks[0], ids, axis=0)
+        self._cover(int(ids.max()) + 1)
+        blk, row = np.divmod(ids, self.block)
+        first, last = int(blk.min()), int(blk.max())
+        if first == last:
+            return np.take(self.blocks[first], row, axis=0)
+        out = np.empty((len(ids),) + self.blocks[0].shape[1:],
+                       self.blocks[0].dtype)
+        for b in range(first, last + 1):
+            sel = blk == b
+            out[sel] = np.take(self.blocks[b], row[sel], axis=0)
+        return out
 
     def take(self, n: int):
         ids = np.arange(self.next_id, self.next_id + n, dtype=np.int64)
@@ -414,17 +422,6 @@ def drain(server) -> None:
     getattr(server, "engine", server).pump(drain=True)
 
 
-# --------------------------------------------------------------- warm-up
-def warm_udfs(model: Model) -> None:
-    """Every UDF batch shape the engine can send: tiles of ``tile`` rows
-    or fewer, padded to multiples of ``udf_pad_rows``."""
-    f = model.cfg["n_features"]
-    pad = int(model.cfg["udf_pad_rows"])
-    for j in range(model.fwd.n_udfs):
-        for n in range(pad, int(model.cfg["tile"]) + 1, pad):
-            model.fwd(j, np.zeros((n, f), np.float32))
-
-
 # ----------------------------------------------------------------- check
 def limits_of(cfg: dict) -> dict:
     lim = dict(cfg["limits"])
@@ -445,9 +442,11 @@ def check(model: Model, src: RecordSource, tracker: Tracker, engines: list,
     """Compare what the timed path produced in the window with the plain
     reference.  Returns {name: (value, op, limit)}; numbers reported but
     not compared go to ``info``."""
+    reference = model.reference
     ids_all = np.arange(n_window, dtype=np.int64)
     x_win = src.rows(ids_all + tracker.base)
-    labels = model.orig_labels(x_win)
+    labels = model.family.orig_labels(x_win)
+    x_proxy = model.family.proxy_inputs(x_win)
     out = {}
     # conservation: every window record finalized exactly once per query
     lost = dup = 0
@@ -480,9 +479,10 @@ def check(model: Model, src: RecordSource, tracker: Tracker, engines: list,
     for k, ids, masks in tracker.captures:
         if k not in cols_cache:
             cols = proxied_columns(plans_per_engine[k])
-            ref = np.stack([reference.proxy_scores(p, x_win) for p, _ in cols],
-                           axis=1)
-            stated = np.stack([reference.stated_scores(p, x_win, bf16_operands)
+            ref = np.stack([reference.proxy_scores(p, x_proxy)
+                            for p, _ in cols], axis=1)
+            stated = np.stack([reference.stated_scores(p, x_proxy,
+                                                       bf16_operands)
                                for p, _ in cols], axis=1)
             thr = np.asarray([t for _, t in cols], np.float64)
             spread = np.maximum(ref.std(axis=0), 1e-12)
@@ -503,6 +503,7 @@ def check(model: Model, src: RecordSource, tracker: Tracker, engines: list,
                               limits["kernel_flip_ppm_max"])
     out["kernel_decisions"] = (decisions, ">=", 1)
     info["kernel_gap_f64"] = gap
+    out.update(model.family.extra_checks(x_win, limits))
     return out
 
 
@@ -541,8 +542,9 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
     rehearsal (``require_chip``), sizes other than the files'
     (``config_override``, ``traffic_override``), one model for
     several runs in one process (``reuse``), the program's own int8
-    cascade as the control (``quant_dtype``), and the extracted trace
-    written to a file (``keep_trace``)."""
+    cascade as the control (``quant_dtype``, which otherwise comes from
+    the configuration's ``quant_dtype`` key, float32 where absent), and
+    the extracted trace written to a file (``keep_trace``)."""
     t_start = time.perf_counter() if t_start is None else t_start
     spec = load_spec()
     cell = resolve_cell(spec, workload)
@@ -552,6 +554,8 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
     traffic.update(traffic_override or {})
     chips = int(cell["cell"]["chips"])
     devs = device_info(chips, require_chip)
+    if quant_dtype is None:
+        quant_dtype = cfg.get("quant_dtype")
 
     import jax
 
@@ -590,8 +594,8 @@ def run(workload: str, seed: int, seconds: float, traced: bool, *,
         raise ValueError("a cell serves one query")
     tracker = Tracker(1)
     instrument(engines, scorers, tracker, probe)
-    src = RecordSource(model.process, seed, int(traffic["setup_rows"]))
-    warm_udfs(model)
+    src = RecordSource(model.family, seed, int(traffic["setup_rows"]))
+    model.family.warm()
     t_warm = time.perf_counter()
 
     if traffic["loop"] != "closed":
@@ -730,7 +734,7 @@ class ReaderContext:
     def cascade_work(self):
         """(FLOPs, bytes) the window's scorer calls needed, from the
         plan's shapes."""
-        f = int(self.cfg["n_features"])
+        f = int(self.model.family.n_proxy_features)
         flops = nbytes = 0.0
         for i, hidden in enumerate(self.scorer_hidden):
             rows = self.counter(f"score_rows.{i}")
@@ -743,4 +747,4 @@ class ReaderContext:
         return counts.least_time_s(*self.cascade_work(), self.peaks)
 
     def udf_flops(self) -> float:
-        return counts.mlp_flops(self.counter("udf_rows"), self.model.udf_dims)
+        return self.model.family.udf_flops(self.counter("udf_rows"))
