@@ -202,6 +202,47 @@ MAMBA2_27B = ModelConfig(
     source="[arXiv:2405.21060; unverified]",
 )
 
+# --------------------------------------------------------------------------
+# [moe] Moonlight-16B-A3B (model_type deepseek_v3) — 27L d=2048 16H, MLA
+# kv_lora=512 with no q compression, qk_nope/rope/v 128/64/128, RoPE
+# theta 50000 without scaling; first layer dense (d_ff=11264), then 2
+# shared + 64 routed experts of width 1408, top-6, sigmoid scores with a
+# score-correction bias for the choice (noaux_tc, one group), weights
+# renormalized and scaled by 2.446; vocab 163840, rms eps 1e-5.  Served as
+# a classifier UDF's backbone (``models/classifier.py``); not in ``ALL``,
+# whose dry-run sweep trains with the dropping softmax router.
+MOONLIGHT_16B_A3B = ModelConfig(
+    name="moonlight-16b-a3b",
+    family="moe",
+    num_layers=27,
+    d_model=2048,
+    d_ff=1408,
+    vocab_size=163840,
+    attention=AttentionConfig(
+        kind="mla",
+        num_heads=16,
+        num_kv_heads=16,
+        head_dim=128,
+        rope_theta=50000.0,
+        q_lora_rank=0,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+    ),
+    moe=MoEConfig(
+        num_experts=64,
+        top_k=6,
+        expert_ff=1408,
+        num_shared=2,
+        first_dense=1,
+        dense_ff=11264,
+        routed_scaling_factor=2.446,
+    ),
+    norm_eps=1e-5,
+    source="[hf:moonshotai/Moonlight-16B-A3B config.json]",
+)
+
 ALL = {
     "seamless-m4t-medium": SEAMLESS_M4T_MEDIUM,
     "llama3-405b": LLAMA3_405B,

@@ -45,6 +45,9 @@ class MoEConfig:
     dense_ff: int = 0  # d_ff used by those dense layers
     capacity_factor: float = 1.25
     router_aux_weight: float = 1e-3
+    # scale of the renormalized top-k weights of DeepSeek-V3's router, which
+    # only ``moe.held_experts_apply`` runs (``moe_apply`` routes by softmax)
+    routed_scaling_factor: float = 1.0
 
 
 @dataclass(frozen=True)

@@ -25,6 +25,8 @@ is property-tested round-trip in ``tests/test_proxy_family.py``.
 """
 from __future__ import annotations
 
+import functools
+import re
 from dataclasses import dataclass
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple, Type
 
@@ -62,10 +64,18 @@ def register_family(family: ProxyFamily, *, aliases: Sequence[str] = ()) -> Prox
     return family
 
 
+_COLUMN_RANGE = re.compile(r"^(\w+)\[(\d+):(\d+)\]$")
+
+
 def get_family(name: str) -> ProxyFamily:
-    """Resolve a family by canonical name or legacy alias ("svm", "mlp")."""
+    """Resolve a family by canonical name or legacy alias ("svm", "mlp"),
+    or a column-range family by ``<name>[lo:hi]`` (``column_range``)."""
     key = _ALIASES.get(name, name)
     if key not in _REGISTRY:
+        m = _COLUMN_RANGE.match(name)
+        if m is not None:
+            return column_range(get_family(m.group(1)).name,
+                                int(m.group(2)), int(m.group(3)))
         raise KeyError(f"unknown proxy family {name!r}; registered: {sorted(_REGISTRY)}")
     return _REGISTRY[key]
 
@@ -104,6 +114,49 @@ MLP1 = register_family(
     ),
     aliases=("mlp",),
 )
+
+
+def _widen(params: pm.LinearParams, lo: int, n_features: int) -> pm.LinearParams:
+    """Linear params over columns ``[lo, lo + len(w))`` as params over all
+    ``n_features`` columns: weight 0, mean 0 and scale 1 elsewhere, so every
+    other column adds an exact 0 to the score."""
+    w = np.zeros(n_features, np.float32)
+    mean = np.zeros(n_features, np.float32)
+    scale = np.ones(n_features, np.float32)
+    hi = lo + int(np.shape(params.w)[0])
+    w[lo:hi] = np.asarray(params.w, np.float32)
+    mean[lo:hi] = np.asarray(params.mean, np.float32)
+    scale[lo:hi] = np.asarray(params.scale, np.float32)
+    return pm.LinearParams(w=w, b=np.float32(params.b), mean=mean, scale=scale)
+
+
+def _narrow(params: pm.LinearParams, lo: int, hi: int) -> pm.LinearParams:
+    return pm.LinearParams(w=params.w[lo:hi], b=params.b,
+                           mean=params.mean[lo:hi], scale=params.scale[lo:hi])
+
+
+@functools.lru_cache(maxsize=None)
+def column_range(base: str, lo: int, hi: int) -> ProxyFamily:
+    """The linear family restricted to the feature columns ``[lo, hi)``:
+    trained and scored on those columns alone (bit-identical to ``base``
+    on ``x[:, lo:hi]``), its params widened to every column (``_widen``),
+    so packing, the fused kernel and every consumer of ``LinearParams`` run
+    unchanged.  Named ``"linear[lo:hi]"``; ``get_family("svm[lo:hi]")``
+    resolves to it."""
+    if base != LINEAR.name or not 0 <= lo < hi:
+        raise KeyError(f"no column-range family {base}[{lo}:{hi}]: only the "
+                       f"linear family over a non-empty range has one")
+
+    def train(x, y, seed):
+        return _widen(LINEAR.train(x[:, lo:hi], y, seed), lo, x.shape[1])
+
+    return ProxyFamily(
+        name=f"{LINEAR.name}[{lo}:{hi}]",
+        params_cls=pm.LinearParams,
+        train=train,
+        score=lambda p, x: LINEAR.score(_narrow(p, lo, hi), x[:, lo:hi]),
+        pack=LINEAR.pack,
+    )
 
 
 def _packed_train_unsupported(x, y, seed):

@@ -6,10 +6,12 @@ An ML inference query is::
     WHERE c_1 IN v_1 AND ... AND c_n IN v_n      [TARGET ACCURACY A]
 
 Each ``MLUDF`` is a row processor (one output label per input record) that
-wraps an expensive model; each ``Predicate`` tests the UDF's output column.
+wraps an expensive model (a host callable, or a ``DeviceUDF`` whose model
+runs on the device); each ``Predicate`` tests the UDF's output column.
 """
 from __future__ import annotations
 
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, FrozenSet, List, Optional, Tuple
 
@@ -27,6 +29,68 @@ class MLUDF:
 
     def __call__(self, x: np.ndarray) -> np.ndarray:
         return np.asarray(self.fn(x))
+
+
+class DeviceUDF:
+    """A UDF whose model runs on the device: ``fn`` of an ``MLUDF``.
+
+    ``forward(params, rows) -> (logits (B, C), stats (S,) int32)`` is one
+    jitted batch program; the weights are its argument, so UDFs that share
+    ``forward`` share its compiled programs.  A call cuts ``x`` into pieces
+    of at most the largest bucket, pads each piece with zero rows to the
+    smallest bucket that holds it (so only ``len(buckets)`` shapes ever
+    compile), dispatches every piece and then fetches all logits and
+    stats in one transfer.  Labels are the logits' argmax.
+
+    ``counters`` gets one row per call: (``advisory_wall_ms()`` at the
+    call, records, record slots, the pieces' stats summed); it keeps the
+    latest ``KEEP_CALLS`` calls.  Spans: ``udf.pad``, ``udf.dispatch``,
+    ``udf.fetch``."""
+
+    KEEP_CALLS = 1 << 16
+
+    def __init__(self, forward, params, buckets):
+        self.forward = forward
+        self.params = params
+        self.buckets = tuple(sorted(int(b) for b in buckets))
+        self.counters: deque = deque(maxlen=self.KEEP_CALLS)
+
+    def bucket(self, n: int) -> int:
+        for b in self.buckets:
+            if n <= b:
+                return b
+        raise ValueError(f"{n} rows exceed the largest bucket {self.buckets[-1]}")
+
+    def logits(self, x: np.ndarray) -> np.ndarray:
+        """(len(x), C) float32 logits; ``x`` must hold at least one row."""
+        import jax
+
+        from repro.util import advisory_wall_ms, spans
+
+        t = advisory_wall_ms()
+        x = np.asarray(x, np.float32)
+        if not len(x):
+            raise ValueError("a DeviceUDF call needs at least one row")
+        top = self.buckets[-1]
+        with spans.span("udf.pad"):
+            pieces = []
+            for s in range(0, len(x), top):
+                part = x[s:s + top]
+                xp = np.zeros((self.bucket(len(part)), x.shape[1]), np.float32)
+                xp[:len(part)] = part
+                pieces.append(xp)
+        with spans.span("udf.dispatch"):
+            outs = [self.forward(self.params, xp) for xp in pieces]
+        with spans.span("udf.fetch"):
+            got = jax.device_get(outs)
+        self.counters.append((t, len(x), sum(len(xp) for xp in pieces),
+                              np.sum([st for _, st in got], axis=0)))
+        return np.concatenate([lg for lg, _ in got])[:len(x)]
+
+    def __call__(self, x: np.ndarray) -> np.ndarray:
+        if not len(x):
+            return np.zeros(0, np.int64)
+        return np.argmax(self.logits(x), axis=1)
 
 
 @dataclass

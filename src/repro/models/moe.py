@@ -10,6 +10,13 @@ sharded on E — XLA materializes the all-to-all from the resharding.
 DeepSeek-V2-Lite layers use MLA attention + (2 shared + 64 routed top-6)
 experts with the first layer dense; qwen3 uses GQA(+qk-norm) + 128 routed
 top-8.
+
+``held_experts_apply`` is the dropless layer of one expert-parallel rank:
+it holds a contiguous range of the routed experts, routes every token over
+all of them (``route``: DeepSeek-V3's sigmoid router with its correction
+bias), and computes its own experts' part of the result for every token
+routed to them, in static-size blocks of a token list sorted by expert, so
+no token is dropped under any imbalance.
 """
 from __future__ import annotations
 
@@ -180,6 +187,113 @@ def moe_apply_ep(p, cfg, x):
     if m.num_shared:
         out = out + L.mlp_apply(p["shared"], cfg, x.reshape(-1, x.shape[-1])).reshape(x.shape)
     return out, aux
+
+
+# ------------------------------------------------- held experts (dropless)
+def route(logits, bias, moe_cfg):
+    """DeepSeek-V3's top-k choice over all experts (``noaux_tc``, one
+    group) from float32 router logits (N, E) and the correction bias (E,).
+
+    Returns ``(top_e (N, K) int32, top_w (N, K) float32)``: the scores are
+    the sigmoid of the logits; the choice ranks ``scores + bias``; the
+    weights are the chosen experts' scores, renormalized over the K chosen
+    and multiplied by ``routed_scaling_factor``."""
+    scores = jax.nn.sigmoid(logits)
+    _, top_e = lax.top_k(scores + bias, moe_cfg.top_k)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_e, top_w * moe_cfg.routed_scaling_factor
+
+
+def init_held_experts(key, cfg, n_held: int):
+    """Router over all ``num_experts`` with its correction bias (seeded
+    small and nonzero), the weights of ``n_held`` routed experts, and the
+    shared experts as one MLP."""
+    m = cfg.moe
+    d, f = cfg.d_model, m.expert_ff
+    dt = L.param_dtype(cfg)
+    ks = jax.random.split(key, 6)
+    p = {
+        "router": L.dense_init(ks[0], (d, m.num_experts), dtype=dt),
+        "wg": L.dense_init(ks[1], (n_held, d, f), dtype=dt),
+        "wi": L.dense_init(ks[2], (n_held, d, f), dtype=dt),
+        "wo": L.dense_init(ks[3], (n_held, f, d), in_axis=-2, dtype=dt),
+        "bias": 0.02 * jax.random.normal(ks[4], (m.num_experts,), jnp.float32),
+    }
+    if m.num_shared:
+        p["shared"] = L.init_mlp(ks[5], cfg, d_ff=m.num_shared * m.expert_ff)
+    return p
+
+
+def router_logits(p, x):
+    """float32 router logits: the activations and weights upcast and
+    multiplied at full float32 precision, as DeepSeek-V3's gate does."""
+    return jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
+def expert_block(n_tokens: int, moe_cfg) -> int:
+    """Tokens per block of the held-expert loop: about a third of an
+    expert's mean load, a power of two in [128, 1024]."""
+    mean = n_tokens * moe_cfg.top_k / moe_cfg.num_experts
+    b = 128
+    while b < 1024 and 3 * b < mean:
+        b *= 2
+    return b
+
+
+def held_load(top_e, first: int, n_held: int, mask=None):
+    """Assignments (token, chosen expert) per held expert, (n_held,) int32;
+    with ``mask`` (N,) only those of the masked tokens."""
+    hit = top_e[..., None] == (first + jnp.arange(n_held))  # (N, K, n)
+    if mask is not None:
+        hit = hit & mask[:, None, None]
+    return jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+
+
+def held_experts_apply(p, cfg, x, first: int):
+    """Dropless expert layer of the rank holding routed experts
+    ``[first, first + n_held)``; x (N, D) -> (out (N, D), top_e (N, K)).
+
+    Routes over all experts, then sorts the (token, expert) assignments by
+    held expert (assignments to experts held elsewhere sort last and are
+    not computed).  Each held expert runs its gated MLP over its own tokens
+    in blocks of ``expert_block`` rows, as many blocks as its load needs,
+    and adds them weighted into the output: static shapes, no capacity, no
+    token dropped.  The shared experts run on every token.  Expert outputs
+    accumulate in float32; ``out`` is in ``x``'s dtype."""
+    m = cfg.moe
+    n_held = p["wg"].shape[0]
+    N, D = x.shape
+    K = m.top_k
+    top_e, top_w = route(router_logits(p, x), p["bias"], m)
+    local = top_e - first
+    mine = (local >= 0) & (local < n_held)
+    flat_e = jnp.where(mine, local, n_held).reshape(-1)  # (N*K,)
+    flat_w = jnp.where(mine, top_w, 0.0).reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    block = expert_block(N, m)
+    pad = jnp.zeros((block,), jnp.int32)
+    tok = jnp.concatenate([(order // K).astype(jnp.int32), pad])
+    wgt = jnp.concatenate([flat_w[order], pad.astype(jnp.float32)])
+    load = jnp.bincount(flat_e, length=n_held + 1)[:n_held].astype(jnp.int32)
+    start = jnp.cumsum(load) - load
+    act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+    out = jnp.zeros((N, D), jnp.float32)
+    for e in range(n_held):
+        def body(b, acc, e=e):
+            s = start[e] + b * block
+            t = lax.dynamic_slice(tok, (s,), (block,))
+            w = lax.dynamic_slice(wgt, (s,), (block,))
+            w = jnp.where(b * block + jnp.arange(block) < load[e], w, 0.0)
+            xb = x[t]
+            h = act(xb @ p["wg"][e]) * (xb @ p["wi"][e])
+            return acc.at[t].add((h @ p["wo"][e]).astype(jnp.float32)
+                                 * w[:, None])
+        out = lax.fori_loop(0, (load[e] + block - 1) // block, body, out)
+    if m.num_shared:
+        out = out + L.mlp_apply(p["shared"], cfg, x).astype(jnp.float32)
+    return out.astype(x.dtype), top_e
 
 
 def _moe_dispatch(p, cfg, x):

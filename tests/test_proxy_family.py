@@ -186,3 +186,40 @@ def test_builder_mixed_assigns_families_and_keys_cache():
     # exact assignment, parity rule or not)
     b3 = ProxyBuilder(q, ds.x[:800], kind={0: "mlp1", 1: "linear"})
     assert b3.family_for(0) == "mlp1" and b3.family_for(1) == "linear"
+
+
+# --------------------------------------------------- column-range family
+def test_column_range_family_equals_an_svm_on_the_prefix_alone():
+    """``svm[0:16]`` over 40 columns trains and scores on the first 16
+    alone: the same masks as the linear family given those columns only;
+    its params are widened so that every other column adds an exact 0,
+    through the packed form and the float64 proxy reference alike."""
+    from repro.core.proxy import train_proxy
+    from repro.core.proxy_family import LINEAR, column_range
+
+    rng = np.random.RandomState(7)
+    x = rng.randn(600, 40).astype(np.float32)
+    x[:, 16:] = rng.randint(1, 20000, (600, 24))   # token ids, never read
+    y = (x[:, :16] @ rng.randn(16) + 0.3 * rng.randn(600)) > 0
+    fam = get_family("svm[0:16]")
+    assert fam is get_family("linear[0:16]") is column_range("linear", 0, 16)
+    assert fam.name == "linear[0:16]" and family_of(
+        fam.train(x, np.where(y, 1.0, -1.0), 0)) is LINEAR
+    wide = train_proxy(x, y, 0, (), kind="svm[0:16]", seed=3)
+    narrow = train_proxy(x[:, :16], y, 0, (), kind="svm", seed=3)
+    assert wide.family == "linear[0:16]"
+    for alpha in (0.8, 0.9, 0.95):
+        np.testing.assert_array_equal(wide.mask(x, alpha),
+                                      narrow.mask(x[:, :16], alpha))
+    p = wide.params
+    assert p.w.shape == (40,) and not p.w[16:].any()
+    assert not p.mean[16:].any() and (p.scale[16:] == 1).all()
+    np.testing.assert_array_equal(p.w[:16], np.asarray(narrow.params.w))
+    z = (x.astype(np.float64) - p.mean) / p.scale
+    np.testing.assert_array_equal(
+        z @ p.w.astype(np.float64),
+        z[:, :16] @ np.asarray(narrow.params.w, np.float64))
+    packed = fam.pack(p)
+    assert not packed.w1[16:].any()
+    with pytest.raises(KeyError):
+        get_family("mlp[0:16]")

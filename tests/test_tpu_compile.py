@@ -149,3 +149,23 @@ def test_udf_forward_compiles_for_v5e(one_chip):
     fwd = jax.jit(lambda p, xx: jnp.argmax(logits_fn(p, xx), axis=-1))
     compiled = fwd.lower(p_specs, x_spec).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_moonlight_classifier_forward_compiles_for_v5e(one_chip):
+    """The Moonlight-16B-A3B classifier UDF at its published widths (the
+    chip's share: 8 held experts, a 20,480-id vocabulary slice, 5
+    layers) on its smallest record bucket: 8 records of 256 tokens after
+    a 128-column sketch.  Its weights fit with room (about 1.05 GB)."""
+    from repro.configs.archs import MOONLIGHT_16B_A3B
+    from repro.models import classifier
+
+    cfg = MOONLIGHT_16B_A3B.replace(num_layers=5, vocab_size=20480)
+    shapes = jax.eval_shape(lambda k: classifier.init(
+        k, cfg, n_held=8, n_classes=4), jax.random.PRNGKey(0))
+    p_specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    x_spec = jax.ShapeDtypeStruct((8, 384), jnp.float32, sharding=one_chip)
+    fwd = classifier.row_forward(cfg, held_first=0, token_col=128)
+    mem = fwd.lower(p_specs, x_spec).compile().memory_analysis()
+    assert 1.0e9 < mem.argument_size_in_bytes < 1.1e9
